@@ -10,11 +10,13 @@ import jax
 
 from repro.core import synth_feature_map
 
-# v5e-class roofline constants — ONE definition, in repro.obs.constants
-# (re-exported by the registry, the cost dispatch every planner/autotune
-# decision routes through); a fitted obs.calibrate.CalibrationDB overrides
-# them per (kind, impl) via the calibration= parameters, never by mutation
-from repro.graph.registry import HBM_BW, PEAK_FLOPS  # noqa: E402,F401
+# the modeled-TPU columns price a v5e at its published peaks — ONE
+# definition, in repro.obs.constants; a fitted obs.calibrate.CalibrationDB
+# overrides them per (kind, impl) via the calibration= parameters
+from repro.obs.constants import DEVICE_PEAKS  # noqa: E402
+
+PEAK_FLOPS = DEVICE_PEAKS["TPU v5 lite"].peak_flops
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"].hbm_bw
 
 
 def time_fn(f, *args, iters: int = 5, warmup: int = 2) -> float:

@@ -18,7 +18,7 @@ import argparse
 import json
 import pathlib
 
-from repro.obs.constants import DEFAULT_ROOFLINE
+from repro.obs.constants import DEVICE_PEAKS
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 DRY = ROOT / "experiments" / "dryrun"
@@ -41,7 +41,7 @@ def reprice(rec: dict, calibration=None) -> dict:
     for old records without the raw fields."""
     if rec.get("status") != "ok" or "hlo_flops_per_device" not in rec:
         return rec
-    consts = DEFAULT_ROOFLINE if calibration is None else \
+    consts = DEVICE_PEAKS["TPU v5 lite"] if calibration is None else \
         calibration.constants_for("conv", "dense")
     out = dict(rec)
     out["compute_term_s"] = rec["hlo_flops_per_device"] / consts.peak_flops

@@ -11,30 +11,22 @@ side by side. The sweep replays the same open-loop request stream at each
 (devices, rate) point on a simulated clock carrying real measured execution
 wall times, and reports throughput and latency percentiles per point.
 
-On this CPU host the "devices" are XLA host-platform virtual devices (the
-module forces `--xla_force_host_platform_device_count` before jax
-initializes), so absolute scaling numbers are synthetic — the artifact
-pins the harness shape (per-device throughput points, compile counts,
-bit-exactness of the serving path) that a real accelerator run fills in.
+On a CPU host the "devices" are XLA host-platform virtual devices (run it
+under `XLA_FLAGS=--xla_force_host_platform_device_count=4`), so absolute
+scaling numbers are synthetic — the artifact pins the harness shape
+(per-device throughput points, compile counts, bit-exactness of the serving
+path) that a real accelerator run fills in. On a TPU host the sweep spans
+the chips JAX finds.
 
 Emits BENCH_serve_sharded.json (always — this is the scale-out head of the
 perf trajectory) in addition to the usual CSV rows.
 
-Run: PYTHONPATH=src:. python benchmarks/serve_sharded.py [--reduced] [--json DIR]
+Run: XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+         PYTHONPATH=src:. python benchmarks/serve_sharded.py [--reduced] [--json DIR]
 """
 from __future__ import annotations
 
 import argparse
-import os
-import sys
-
-# the virtual-device flag must precede jax initialization; respect an
-# explicit operator setting (or an already-imported jax) and otherwise ask
-# for the sweep's default of 4
-if "jax" not in sys.modules and \
-        "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                               " --xla_force_host_platform_device_count=4").strip()
 
 import jax
 import jax.numpy as jnp

@@ -15,11 +15,12 @@ Checks (DESIGN.md §12):
   RPA102  every index-map gather stays in bounds — the last conv window
           must fit the (already spatially padded) input; block sizes are
           positive so no zero-size BlockSpec divides anything.
-  RPA103  the per-grid-step VMEM tile fits `VMEM_BUDGET_BYTES`. A default
-          resolution can only exceed the budget at the block_c floor of 8
-          (a huge spatial map) — that is a warn; any over-budget tile
-          ABOVE the floor can only come from an explicit request the
-          resolver would otherwise have shrunk — that is an error.
+  RPA103  the launch's modeled scoped VMEM (`vmem_bytes`: padded,
+          double-buffered blocks + accumulator + temporaries) fits
+          `VMEM_LIMIT_BYTES`, the limit every kernel asks Mosaic for. At
+          the default blocks only a huge spatial map can exceed it — that
+          is a warn (it needs a row-band kernel); blocks above the default
+          come from an explicit request — that is an error.
   RPA104  int8 kernels accumulate in int32 and carry per-output-channel
           scales (fp32 accumulation would silently saturate; a single
           tensor scale loses the per-channel dynamic range the quantizer
@@ -30,7 +31,12 @@ Checks (DESIGN.md §12):
 from __future__ import annotations
 
 from repro.analysis.diagnostics import DiagnosticSink
-from repro.kernels.tiles import VMEM_BUDGET_BYTES, BsrLaunch, ConvLaunch
+from repro.kernels.tiles import (
+    VMEM_LIMIT_BYTES,
+    BsrLaunch,
+    ConvLaunch,
+    resolve_conv_tile,
+)
 
 
 def _pad_ok(extent: int, pad: int, block: int, n_blocks: int) -> bool:
@@ -101,18 +107,19 @@ def check_conv_launch(L: ConvLaunch, sink: DiagnosticSink, *,
                      f"epilogue floors, silently truncating the remainder",
                      hint="run the unit unfused (conv + pool) instead", **loc)
 
-    # --- RPA103: VMEM budget ---------------------------------------------
-    tile_bytes = L.x_tile_bytes + L.scratch_bytes
-    if tile_bytes > VMEM_BUDGET_BYTES:
-        explicit = L.block_c > 8  # the default policy shrinks to the floor
+    # --- RPA103: VMEM limit ----------------------------------------------
+    if L.vmem_bytes > VMEM_LIMIT_BYTES:
+        default_bc, default_bo = resolve_conv_tile(L.c, L.o)
+        explicit = L.block_c > default_bc or L.block_o > default_bo
         sink.add("RPA103",
-                 f"{L.kernel}: {tile_bytes} B tile "
+                 f"{L.kernel}: {L.vmem_bytes} B modeled VMEM "
                  f"(x {L.h}x{L.w}x{L.block_c} + acc {L.oh}x{L.ow}x"
-                 f"{L.block_o}) exceeds the {VMEM_BUDGET_BYTES} B VMEM "
-                 f"budget",
+                 f"{L.block_o}) exceeds the {VMEM_LIMIT_BYTES} B VMEM "
+                 f"limit",
                  severity="error" if explicit else "warn",
                  hint=("shrink the requested tile" if explicit else
-                       "spatial map too large even at the block_c floor"),
+                       "spatial map too large for a full-map tile (needs a "
+                       "row-band kernel)"),
                  **loc)
 
     # --- RPA104: int8 accumulation / scale contract ----------------------
@@ -156,12 +163,12 @@ def check_bsr_launch(L: BsrLaunch, sink: DiagnosticSink, *,
                      hint=f"n{name} must equal ceil({name} / b{name}) with "
                           "minimal pad", **loc)
 
-    # --- RPA103: VMEM budget (defaults are tiny; over-budget => explicit)
-    if L.tile_bytes > VMEM_BUDGET_BYTES:
+    # --- RPA103: VMEM limit (defaults are tiny; over the limit => explicit)
+    if L.vmem_bytes > VMEM_LIMIT_BYTES:
         sink.add("RPA103",
-                 f"{L.kernel}: {L.tile_bytes} B resident tile "
+                 f"{L.kernel}: {L.vmem_bytes} B modeled VMEM "
                  f"({L.bt}x{L.bf} + {L.bf}x{L.bd} operands + {L.bt}x{L.bd} "
-                 f"acc) exceeds the {VMEM_BUDGET_BYTES} B VMEM budget",
+                 f"acc) exceeds the {VMEM_LIMIT_BYTES} B VMEM limit",
                  hint="shrink the requested (bt, bf, bd)", **loc)
 
     # --- RPA104: int8 contract -------------------------------------------

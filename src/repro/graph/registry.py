@@ -162,14 +162,9 @@ def unit_impl(unit: ConvUnit, impl: str) -> tuple:
 # Cost dispatch (the one place a unit is costed as a (kind, impl))
 # ---------------------------------------------------------------------------
 
-# THE roofline constants live in repro.obs.constants (one definition, which a
-# measured CalibrationDB overrides per impl); these names stay re-exported so
-# benchmarks/_util, the dry-run and autotune keep one import site.
-from repro.obs.constants import (  # noqa: E402
-    DEFAULT_HBM_BW as HBM_BW,  # noqa: F401
-    DEFAULT_PEAK_FLOPS as PEAK_FLOPS,  # noqa: F401
-    DEFAULT_ROOFLINE,
-)
+# THE roofline constants live in repro.obs.constants (peaks per device
+# kind, which a measured CalibrationDB overrides per impl)
+from repro.obs.constants import device_peaks  # noqa: E402
 
 
 def _pool_round_trip(base: dict, pool: int, dtype_bytes: int = 4) -> dict:
@@ -216,9 +211,9 @@ def unit_model_us(kind: str, impl: str, unit: ConvUnit, *,
     `calibration` (a `repro.obs.calibrate.CalibrationDB`, or None) supplies
     MEASURED effective constants per (device kind, kind, impl, tile geometry);
     any key the DB does not cover — and calibration=None entirely — falls
-    back to the datasheet defaults, bit-identically to the pre-calibration
-    model. `block_c` is the plan's channel-block size (0 = auto) and `tile`
-    the full searched `TileConfig` (None = defaults) — together the block
+    back to the device's published peaks (`obs.constants.device_peaks`).
+    `block_c` is the plan's channel-block size (0 = auto) and `tile` the
+    full searched `TileConfig` (None = defaults) — together the block
     geometry the calibration is keyed on."""
     conv = unit.conv
     c, h, w = unit.in_shape
@@ -227,7 +222,7 @@ def unit_model_us(kind: str, impl: str, unit: ConvUnit, *,
                      pool=unit.pool.p if unit.pool is not None else None,
                      occupancy=occupancy, weight_density=weight_density,
                      batch=batch)
-    consts = DEFAULT_ROOFLINE if calibration is None else \
+    consts = device_peaks() if calibration is None else \
         calibration.constants_for(kind, impl, block_c, tile=tile)
     return consts.time_us(cost["flops"], cost["bytes"])
 

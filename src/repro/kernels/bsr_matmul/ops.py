@@ -22,9 +22,8 @@ def block_schedule(h: jax.Array, bt: int, bf: int):
     return ids, cnt
 
 
-@partial(jax.jit, static_argnames=("block", "interpret", "tile"))
-def sparse_matmul(h, w, block=(8, 128, 128), interpret: bool = True,
-                  tile=None):
+@partial(jax.jit, static_argnames=("block", "tile"))
+def sparse_matmul(h, w, block=(8, 128, 128), tile=None):
     """y = h @ w skipping all-zero (bt,bf) blocks of h. Pads to block multiples.
 
     `tile` (a `repro.kernels.tiles.TileConfig`) overrides the (bt, bf, bd)
@@ -46,8 +45,7 @@ def sparse_matmul(h, w, block=(8, 128, 128), interpret: bool = True,
     # launch at the RESOLVED geometry — passing the default `block` here while
     # padding/scheduling at the tile override was exactly the silent
     # grid-vs-schedule mismatch repro.analysis' RPA101 check exists to catch
-    y = bsr_matmul_pallas(hp, wp, ids, cnt, block=(bt, bf, bd),
-                          interpret=interpret)
+    y = bsr_matmul_pallas(hp, wp, ids, cnt, block=(bt, bf, bd))
     return y[:t, :d]
 
 

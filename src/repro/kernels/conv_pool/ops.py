@@ -13,10 +13,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.sparsity import block_occupancy, compact_block_ids
-from repro.kernels.conv_pool.kernel import conv_pool_pallas, conv_pool_pallas_batch
-from repro.kernels.ecr_conv.ops import batch_block_schedule, ecr_conv_launch
-from repro.kernels.schedule_guard import guard_schedule
+from repro.kernels.ecr_conv.ops import (
+    as_conv_operands,
+    ecr_conv_launch,
+    run_conv_kernel,
+)
 from repro.kernels.tiles import ConvLaunch, TileConfig
 
 
@@ -35,62 +36,29 @@ def conv_pool_launch(c: int, h: int, w: int, o: int, kh: int = 3, kw: int = 3,
                            acc_dtype=acc_dtype, weight_scales=weight_scales)
 
 
-@partial(jax.jit, static_argnames=("stride", "pool", "p_s", "interpret", "block_c", "block_o", "compact"))
+@partial(jax.jit, static_argnames=("stride", "pool", "p_s", "block_c", "block_o", "compact"))
 def fused_conv_pool(x_chw, kernels_oihw, stride: int = 1, pool: int = 2,
-                    p_s=None, interpret: bool = True, block_c: int = 0,
-                    block_o: int = 0, compact: bool = True):
+                    p_s=None, block_c: int = 0, block_o: int = 0,
+                    compact: bool = True):
     """(C,H,W) x (O,C,kh,kw) -> (O, oh//p, ow//p). p_s must equal pool (kernel form).
-    Batched: (N,C,H,W) -> (N, O, oh//p, ow//p) through the native batched grid
-    with per-sample channel-block schedules (shared-union compaction)."""
-    from repro.core.ecr import compact_live_channels, compact_live_channels_batch
+    Batched: (N,C,H,W) -> (N, O, oh//p, ow//p) through the same batched grid
+    as `ecr_conv`, per-sample channel-block schedules, shared-union
+    compaction, and the PECR epilogue on the last channel block."""
+    from repro.core.ecr import compact_live_channels_batch
 
     assert p_s is None or p_s == pool, "pallas kernel supports pooling stride == pool"
-    if x_chw.ndim == 2:
-        x_chw = x_chw[None]
-    if kernels_oihw.ndim == 3:
-        kernels_oihw = kernels_oihw[None]
-    batched = x_chw.ndim == 4
-    c, h, w = x_chw.shape[-3:]
-    o, c2, kh, kw = kernels_oihw.shape
+    x, kernels_oihw, single = as_conv_operands(x_chw, kernels_oihw)
+    n, c, h, w = x.shape
+    o, _, kh, kw = kernels_oihw.shape
     # the ONE shared (bc, bo) defaulting rule (repro.kernels.tiles), not a
-    # drifting copy of ecr_conv's — dtype_bytes rides the VMEM-budget pick
+    # drifting copy of ecr_conv's
     launch = conv_pool_launch(c, h, w, o, kh, kw, stride=stride, pool=pool,
-                              block_c=block_c, block_o=block_o,
-                              batch=x_chw.shape[0] if batched else 1,
-                              dtype_bytes=jnp.dtype(x_chw.dtype).itemsize)
-    bc, bo = launch.block_c, launch.block_o
-    cp, op, n_cb = launch.c_pad, launch.o_pad, launch.n_cb
-
-    if batched:
-        assert x_chw.shape[0] > 0, "empty batch: fused_conv_pool needs N >= 1"
-        if compact:
-            x_chw, kernels_oihw, _ = compact_live_channels_batch(x_chw, kernels_oihw)
-        x = jnp.pad(x_chw, ((0, 0), (0, cp), (0, 0), (0, 0))).transpose(0, 2, 3, 1)
-        wk = jnp.pad(kernels_oihw, ((0, op), (0, cp), (0, 0), (0, 0))).transpose(2, 3, 1, 0)
-        ids, cnt = batch_block_schedule(x, h, w, bc)
-        ids, cnt = guard_schedule(ids, cnt, n_cb)
-        out = conv_pool_pallas_batch(
-            x, wk, ids, cnt, stride=stride, pool=pool, block_c=bc, block_o=bo,
-            interpret=interpret,
-        )
-        return out.transpose(0, 3, 1, 2)[:, :o]
-
+                              block_c=block_c, block_o=block_o, batch=n,
+                              dtype_bytes=jnp.dtype(x.dtype).itemsize)
     if compact:
-        x_chw, kernels_oihw, n_live = compact_live_channels(x_chw, kernels_oihw)
-    x = jnp.pad(x_chw, ((0, cp), (0, 0), (0, 0))).transpose(1, 2, 0)
-    wk = jnp.pad(kernels_oihw, ((0, op), (0, cp), (0, 0), (0, 0))).transpose(2, 3, 1, 0)
-    if compact:
-        ids = jnp.arange(n_cb, dtype=jnp.int32)
-        cnt = jnp.minimum((n_live + bc - 1) // bc, n_cb).astype(jnp.int32)
-    else:
-        occ = block_occupancy(x, (h, w, bc)).reshape(-1)
-        ids, cnt = compact_block_ids(occ)
-    ids, cnt = guard_schedule(ids, cnt, n_cb)
-    out = conv_pool_pallas(
-        x, wk, ids, cnt[None], stride=stride, pool=pool, block_c=bc, block_o=bo,
-        interpret=interpret,
-    )
-    return out.transpose(2, 0, 1)[:o]
+        x, kernels_oihw, _ = compact_live_channels_batch(x, kernels_oihw)
+    y = run_conv_kernel(x, kernels_oihw, launch)
+    return y[0] if single else y
 
 
 def conv_pool_cost(c: int, h: int, w: int, o: int, kh: int = 3, kw: int = 3, *,

@@ -13,15 +13,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sparsity import block_occupancy, compact_block_ids
-from repro.kernels.ecr_conv.kernel import ecr_conv_pallas, ecr_conv_pallas_batch
-from repro.kernels.schedule_guard import guard_schedule
-from repro.kernels.tiles import (
-    VMEM_BUDGET_BYTES,  # noqa: F401  (re-exported legacy name)
-    ConvLaunch,
-    TileConfig,
-    pick_block_c as _pick_block_c,  # noqa: F401  (re-exported legacy name)
-    resolve_conv_tile,
+from repro.kernels.ecr_conv.kernel import (
+    channel_blocks,
+    conv_pallas,
+    unblock_output,
+    weight_blocks,
 )
+from repro.kernels.schedule_guard import guard_schedule
+from repro.kernels.tiles import ConvLaunch, TileConfig, resolve_conv_tile
 
 
 def ecr_conv_launch(c: int, h: int, w: int, o: int, kh: int = 3, kw: int = 3,
@@ -37,7 +36,7 @@ def ecr_conv_launch(c: int, h: int, w: int, o: int, kh: int = 3, kw: int = 3,
     the legacy (block_c, block_o) scalars; `pool`/`kernel`/`acc_dtype` are
     pass-throughs for the fused and int8 variants that share this builder."""
     t = tile if tile is not None else TileConfig(block_c=block_c, block_o=block_o)
-    bc, bo = resolve_conv_tile(h, w, c, o, t, dtype_bytes=dtype_bytes)
+    bc, bo = resolve_conv_tile(c, o, t)
     cp, op = (-c) % bc, (-o) % bo
     return ConvLaunch(
         kernel=kernel, batch=batch, c=c, h=h, w=w, o=o, kh=kh, kw=kw,
@@ -48,71 +47,70 @@ def ecr_conv_launch(c: int, h: int, w: int, o: int, kh: int = 3, kw: int = 3,
         weight_scales=weight_scales)
 
 
-def batch_block_schedule(x_nhwc, h, w, bc):
-    """Per-sample (ids, cnt) channel-block schedules for a batched (N,H,W,C')
-    tensor: each sample skips its own dead blocks (ragged batch sparsity)."""
-    n = x_nhwc.shape[0]
-    occ = block_occupancy(x_nhwc, (h, w, bc)).reshape(n, -1)  # (N, n_cb)
+def batch_block_schedule(xb):
+    """Per-sample (ids, cnt) channel-block schedules of a blocked
+    (N, n_cb, H, W, bc) tensor: each sample skips its own dead blocks
+    (ragged batch sparsity)."""
+    occ = jnp.any(xb != 0, axis=(2, 3, 4))  # (N, n_cb)
     return jax.vmap(compact_block_ids)(occ)  # ids (N, n_cb), cnt (N,)
 
 
-@partial(jax.jit, static_argnames=("stride", "interpret", "block_c", "block_o", "compact"))
-def ecr_conv(x_chw, kernels_oihw, stride: int = 1, interpret: bool = True,
-             block_c: int = 0, block_o: int = 0, compact: bool = True):
+def as_conv_operands(x_chw, kernels_oihw):
+    """Lift the accepted input ranks to (N, C, H, W) x (O, C, kh, kw):
+    (H, W) is one channel, (C, H, W) one image; 3-D kernels get O = 1.
+    Returns (x, kernels, single) where `single` says to drop the batch."""
+    if x_chw.ndim == 2:
+        x_chw = x_chw[None]
+    if kernels_oihw.ndim == 3:
+        kernels_oihw = kernels_oihw[None]
+    single = x_chw.ndim == 3
+    x = x_chw[None] if single else x_chw
+    assert x.shape[0] > 0, "empty batch: the conv kernels need N >= 1"
+    return x, kernels_oihw, single
+
+
+def run_conv_kernel(x_nchw, kernels_oihw, launch: ConvLaunch, *,
+                    sx=None, sw=None):
+    """Pad to the launch's blocks, schedule per sample, run `conv_pallas`
+    and return (N, O, OH', OW'). `sx` (N,) / `sw` (O,) are the int8
+    activation / weight scales of quantized operands."""
+    bc, bo, cp, op = launch.block_c, launch.block_o, launch.c_pad, launch.o_pad
+    xb = channel_blocks(jnp.pad(x_nchw, ((0, 0), (0, cp), (0, 0), (0, 0))), bc)
+    wb = weight_blocks(
+        jnp.pad(kernels_oihw, ((0, op), (0, cp), (0, 0), (0, 0))), bc, bo)
+    ids, cnt = guard_schedule(*batch_block_schedule(xb), launch.n_cb)
+    if sw is not None:
+        sx = sx.reshape(-1, 1, 1)
+        sw = jnp.pad(sw, (0, op), constant_values=1.0).reshape(
+            launch.n_ob, 1, bo)
+    out = conv_pallas(xb, wb, ids, cnt, stride=launch.stride,
+                      pool=launch.pool, sx=sx, sw=sw)
+    return unblock_output(out)[:, :launch.o]
+
+
+@partial(jax.jit, static_argnames=("stride", "block_c", "block_o", "compact"))
+def ecr_conv(x_chw, kernels_oihw, stride: int = 1, block_c: int = 0,
+             block_o: int = 0, compact: bool = True):
     """(C,H,W) x (O,C,kh,kw) -> (O,oh,ow), skipping dead input channel blocks.
-    Batched: (N,C,H,W) -> (N,O,oh,ow) through the native batched grid.
+    Batched: (N,C,H,W) -> (N,O,oh,ow); one image runs as a batch of one.
 
     compact=True (default): ECR channel compaction first — live channels pack
     into a dense prefix so unstructured channel death still becomes contiguous
     skippable blocks (cnt = ceil(n_live / bc)). For a batch the pack uses one
     shared permutation (union of live channels — kernels stay shared) and
     per-sample raggedness is recovered by per-sample block schedules."""
-    from repro.core.ecr import compact_live_channels, compact_live_channels_batch
+    from repro.core.ecr import compact_live_channels_batch
 
-    if x_chw.ndim == 2:
-        x_chw = x_chw[None]
-    if kernels_oihw.ndim == 3:
-        kernels_oihw = kernels_oihw[None]
-    batched = x_chw.ndim == 4
-    c, h, w = x_chw.shape[-3:]
-    o, c2, kh, kw = kernels_oihw.shape
+    x, kernels_oihw, single = as_conv_operands(x_chw, kernels_oihw)
+    n, c, h, w = x.shape
+    o, _, kh, kw = kernels_oihw.shape
     launch = ecr_conv_launch(c, h, w, o, kh, kw, stride=stride,
-                             block_c=block_c, block_o=block_o,
-                             batch=x_chw.shape[0] if batched else 1,
-                             dtype_bytes=jnp.dtype(x_chw.dtype).itemsize)
-    bc, bo = launch.block_c, launch.block_o
-    cp, op, n_cb = launch.c_pad, launch.o_pad, launch.n_cb
-
-    if batched:
-        assert x_chw.shape[0] > 0, "empty batch: ecr_conv needs N >= 1"
-        if compact:
-            x_chw, kernels_oihw, _ = compact_live_channels_batch(x_chw, kernels_oihw)
-        x = jnp.pad(x_chw, ((0, 0), (0, cp), (0, 0), (0, 0))).transpose(0, 2, 3, 1)
-        wk = jnp.pad(kernels_oihw, ((0, op), (0, cp), (0, 0), (0, 0))).transpose(2, 3, 1, 0)
-        ids, cnt = batch_block_schedule(x, h, w, bc)
-        ids, cnt = guard_schedule(ids, cnt, n_cb)
-        out = ecr_conv_pallas_batch(
-            x, wk, ids, cnt, stride=stride, block_c=bc, block_o=bo,
-            interpret=interpret,
-        )
-        return out.transpose(0, 3, 1, 2)[:, :o]  # (N, O, oh, ow)
-
+                             block_c=block_c, block_o=block_o, batch=n,
+                             dtype_bytes=jnp.dtype(x.dtype).itemsize)
     if compact:
-        x_chw, kernels_oihw, n_live = compact_live_channels(x_chw, kernels_oihw)
-    x = jnp.pad(x_chw, ((0, cp), (0, 0), (0, 0))).transpose(1, 2, 0)  # (H,W,C')
-    wk = jnp.pad(kernels_oihw, ((0, op), (0, cp), (0, 0), (0, 0))).transpose(2, 3, 1, 0)
-    if compact:
-        ids = jnp.arange(n_cb, dtype=jnp.int32)  # identity: prefix is live
-        cnt = jnp.minimum((n_live + bc - 1) // bc, n_cb).astype(jnp.int32)
-    else:
-        occ = block_occupancy(x, (h, w, bc)).reshape(-1)  # (n_cb,)
-        ids, cnt = compact_block_ids(occ)
-    ids, cnt = guard_schedule(ids, cnt, n_cb)
-    out = ecr_conv_pallas(
-        x, wk, ids, cnt[None], stride=stride, block_c=bc, block_o=bo,
-        interpret=interpret
-    )
-    return out.transpose(2, 0, 1)[:o]  # (O, oh, ow)
+        x, kernels_oihw, _ = compact_live_channels_batch(x, kernels_oihw)
+    y = run_conv_kernel(x, kernels_oihw, launch)
+    return y[0] if single else y
 
 
 def ecr_conv_cost(c: int, h: int, w: int, o: int, kh: int = 3, kw: int = 3, *,
@@ -151,7 +149,7 @@ def channel_block_occupancy(x_chw, block_c: int = 128, compact: bool = False) ->
     import math
 
     c, h, w = x_chw.shape
-    bc = resolve_conv_tile(h, w, c, c, TileConfig(block_c=block_c))[0]
+    bc = resolve_conv_tile(c, c, TileConfig(block_c=block_c))[0]
     n_cb = math.ceil(c / bc)
     if compact:
         n_live = int(jnp.any(x_chw != 0, axis=(1, 2)).sum())

@@ -9,8 +9,7 @@ are computed inside jit from traced VALUES, so this is the complementary
 dynamic guard: a traced-safe clamp of both fields into range, applied at the
 `ecr_conv` / `fused_conv_pool` / `sparse_matmul` / `conv2d_bsr` entry points.
 
-Gated by REPRO_CHECK_SCHEDULES=1 (read at trace time, like the interpret
-flag): the default hot path is bit-identical to before — no extra ops in the
+Gated by REPRO_CHECK_SCHEDULES=1 (read at trace time): the default hot path is bit-identical to before — no extra ops in the
 compiled program. On valid schedules the clamp is the identity, so enabling
 the guard never changes correct results; it exists to turn a corrupted
 schedule's silent garbage into in-range (wrong-but-bounded) reads while the

@@ -17,8 +17,7 @@ This module is the single owner of that geometry:
   the kernel ops. An all-zero TileConfig is falsy and means "defaults",
   so legacy `block_c`-only call paths stay bit-identical.
 - `resolve_conv_tile` — THE (bc, bo) defaulting rule the ECR and PECR ops
-  used to duplicate, now shared (and `dtype_bytes`-aware: the VMEM budget
-  is in bytes, so int8 activations fit 4x wider channel blocks).
+  used to duplicate, now shared.
 - `resolve_bsr_tile` — the (bt, bf, bd) rule for the BSR lowering, with the
   same contract.
 
@@ -33,6 +32,16 @@ also the rule `planner.occupancy_stat` and `channel_block_occupancy`
 resolve through, so the measured statistic and the executed schedule can
 never disagree about the block size (the geometry bug this file fixed).
 
+Every block size is legal for Mosaic: the kernels take their operands in
+blocked HBM layouts (channels split as (n_cb, ..., bc), see
+`kernels/ecr_conv/kernel.py`), so the last two dimensions of every block are
+the array's own. What a block costs in VMEM is another matter: Mosaic pads
+the minor dimension of a VMEM tile to the 128-lane width and the one above it
+to the sublane tile, so a block narrower than 128 channels takes the VMEM of
+a full lane width. `ConvLaunch.vmem_bytes` / `BsrLaunch.vmem_bytes` model the
+padded, double-buffered need of one launch; RPA103 holds it to
+`VMEM_LIMIT_BYTES`, the scoped limit every kernel asks Mosaic for.
+
 Stdlib-only (no jax import): sits below kernels/, graph/ and obs/ in the
 import graph so every layer can share it.
 """
@@ -40,7 +49,28 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024  # conservative half of v5e VMEM for x tile
+LANES = 128  # minor dimension of a VMEM tile
+SUBLANES = 8  # 32-bit rows of a VMEM tile (packed types hold 4 // bytes x more)
+# Scoped VMEM every kernel asks Mosaic for (`kernels.platform`) and the
+# ceiling RPA103 holds a launch's modeled need to. A v5e core has 128 MiB of
+# VMEM and a 16 MiB default scoped limit, which VGG's 96x96 first-stage tiles
+# exceed (~24 MiB for the fused conv1_2 + pool).
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# Mosaic's internal scratch on top of the modeled buffers: the 6x6,
+# 512-channel stage-5 conv needs up to ~40 KiB more than its double-buffered
+# blocks and accumulator
+VMEM_SLACK_BYTES = 256 * 1024
+
+
+def _ceil_to(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _slab_bytes(rows: int, cols: int, dtype_bytes: int) -> int:
+    """VMEM bytes of a (rows, cols) slab as Mosaic tiles it: cols padded to
+    the lane width, rows to the sublane tile of the dtype."""
+    sub = SUBLANES * 4 // dtype_bytes
+    return _ceil_to(rows, sub) * _ceil_to(cols, LANES) * dtype_bytes
 
 
 @dataclass(frozen=True)
@@ -85,39 +115,29 @@ def as_tile(tile=None, block_c: int = 0) -> TileConfig:
     return TileConfig(block_c=int(block_c)) if block_c else DEFAULT_TILE
 
 
-def pick_block_c(h: int, w: int, c: int, dtype_bytes: int = 4) -> int:
-    """Largest power-of-two channel block whose (h, w, bc) activation tile
-    fits the VMEM budget — `dtype_bytes` matters: int8 activations fit 4x
-    the channels of fp32 at the same spatial extent."""
-    bc = 128
-    while bc > 8 and h * w * bc * dtype_bytes > VMEM_BUDGET_BYTES:
-        bc //= 2
-    return bc
-
-
-def resolve_block_c(h: int, w: int, c: int, tile: TileConfig | None = None,
-                    dtype_bytes: int = 4) -> int:
-    """The ECR/PECR channel-block size actually run for a (C, h, w) input.
+def resolve_block_c(c: int, tile: TileConfig | None = None) -> int:
+    """The ECR/PECR channel-block size actually run for a C-channel input.
 
     A requested block_c is honored iff 0 < block_c <= max(8, c) (at most one
     block of channel padding — the same bound the default satisfies);
-    anything else falls back to the default policy: the VMEM-budget pick,
-    clamped so a small layer is at most one block."""
+    anything else falls back to the default: one lane width of channels, or
+    one block holding every channel of a narrower layer. The default does
+    not shrink for large maps: a narrower block takes the same lane-padded
+    VMEM, so a map whose 128-channel tile misses the limit needs a row-band
+    kernel instead (RPA103 warns)."""
     bc = tile.block_c if tile is not None else 0
     if bc <= 0 or bc > max(8, c):
-        bc = min(pick_block_c(h, w, c, dtype_bytes), max(8, c))
+        bc = min(LANES, max(8, c))
     return bc
 
 
-def resolve_conv_tile(h: int, w: int, c: int, o: int,
-                      tile: TileConfig | None = None,
-                      dtype_bytes: int = 4) -> tuple:
+def resolve_conv_tile(c: int, o: int, tile: TileConfig | None = None) -> tuple:
     """(bc, bo) for the ECR / PECR conv ops — the one defaulting rule both
     `ecr_conv` and `fused_conv_pool` resolve through (they used to carry
     duplicated copies). bo is clamped into [.., max(8, o)] like the
     hand-fixed default always was; a non-positive request means default."""
-    bc = resolve_block_c(h, w, c, tile, dtype_bytes)
-    bo = tile.block_o if tile is not None and tile.block_o > 0 else 128
+    bc = resolve_block_c(c, tile)
+    bo = tile.block_o if tile is not None and tile.block_o > 0 else LANES
     bo = min(bo, max(8, o))
     return bc, bo
 
@@ -169,15 +189,24 @@ class ConvLaunch:
         return (self.n_ob, self.batch, self.n_cb)
 
     @property
-    def x_tile_bytes(self) -> int:
-        """One (h, w, block_c) activation tile — the VMEM-budget governor
-        `pick_block_c` sizes against."""
-        return self.h * self.w * self.block_c * self.dtype_bytes
-
-    @property
-    def scratch_bytes(self) -> int:
-        """The (oh*ow, block_o) accumulator scratch (fp32/int32: 4 B)."""
-        return self.oh * self.ow * self.block_o * 4
+    def vmem_bytes(self) -> int:
+        """Modeled scoped VMEM of one launch, an upper bound of what Mosaic
+        allocates (tests/test_tpu_compile.py compiles the VGG-19 launches
+        with the limit set to this number): the double-buffered x, weight
+        and output blocks, the fp32/int32 accumulator scratch plus one
+        accumulator-sized dot result and one patch per tap, and for a fused
+        pool the ReLU'd and reshaped conv tile of the epilogue."""
+        db = self.dtype_bytes
+        poh, pow_ = ((self.oh // self.pool, self.ow // self.pool)
+                     if self.pool else (self.oh, self.ow))
+        x = self.h * _slab_bytes(self.w, self.block_c, db)
+        w = self.kh * self.kw * _slab_bytes(self.block_c, self.block_o, db)
+        out = poh * _slab_bytes(pow_, self.block_o, 4)
+        acc = _slab_bytes(self.oh * self.ow, self.block_o, 4)
+        patch = _slab_bytes(self.oh * self.ow, self.block_c, db)
+        epilogue = 2 * acc if self.pool else 0
+        return (2 * (x + w + out) + 2 * acc + patch + epilogue
+                + VMEM_SLACK_BYTES)
 
 
 @dataclass(frozen=True)
@@ -211,11 +240,15 @@ class BsrLaunch:
         return (self.nt, self.nd, self.nf)
 
     @property
-    def tile_bytes(self) -> int:
-        """Resident VMEM per grid step: one block of each operand + the
-        (bt, bd) fp32/int32 accumulator scratch."""
-        operands = (self.bt * self.bf + self.bf * self.bd) * self.dtype_bytes
-        return operands + self.bt * self.bd * 4
+    def vmem_bytes(self) -> int:
+        """Modeled scoped VMEM of one launch (same padding and double
+        buffering as `ConvLaunch.vmem_bytes`): both operand blocks and the
+        output block twice, the accumulator scratch and one dot result."""
+        db = self.dtype_bytes
+        operands = (_slab_bytes(self.bt, self.bf, db)
+                    + _slab_bytes(self.bf, self.bd, db))
+        acc = _slab_bytes(self.bt, self.bd, 4)
+        return 2 * (operands + acc) + 2 * acc + VMEM_SLACK_BYTES
 
 
 def resolve_bsr_tile(o: int, k_taps: int, p: int,

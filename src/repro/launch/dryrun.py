@@ -26,12 +26,14 @@ from repro.parallel.api import axis_rules, logical_spec
 
 OUT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
-# v5e-class hardware constants (roofline terms derive from these; the
-# chip-level pair has ONE definition in repro.obs.constants, re-exported by
-# the registry). Records also carry the RAW hlo flops/bytes so
+# v5e hardware constants (roofline terms derive from these; the chip-level
+# pair has ONE definition, repro.obs.constants.DEVICE_PEAKS). Records also carry the RAW hlo flops/bytes so
 # benchmarks/roofline.py can re-price old artifacts under changed or
 # calibrated constants without re-running the dry run.
-from repro.graph.registry import HBM_BW, PEAK_FLOPS  # noqa: E402
+from repro.obs.constants import DEVICE_PEAKS  # noqa: E402
+
+PEAK_FLOPS = DEVICE_PEAKS["TPU v5 lite"].peak_flops
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"].hbm_bw
 
 LINK_BW = 50e9  # B/s / link ICI
 

@@ -4,8 +4,11 @@ deterministic simulated-clock request stream that carries real measured
 execution times. Any LayerGraph network serves through the same spine —
 pick one with --model.
 
-Run (reduced, CPU-budget):
+Run (reduced graph; on the CPU the Pallas kernels are interpreted):
     PYTHONPATH=src python -m repro.launch.serve_cnn --rate 50 --n-requests 24
+Full VGG-19 depth and widths at 96x96 (on a TPU the kernels compile with
+Mosaic; `python chip_smoke.py` runs this path and checks its logits):
+    PYTHONPATH=src python -m repro.launch.serve_cnn --full
 Other networks:
     PYTHONPATH=src python -m repro.launch.serve_cnn --model lenet
     PYTHONPATH=src python -m repro.launch.serve_cnn --model alexnet
@@ -38,6 +41,7 @@ from __future__ import annotations
 
 import argparse
 import logging
+import time
 
 import jax
 import jax.numpy as jnp
@@ -57,9 +61,10 @@ SCENARIOS = ("steady", "burst", "diurnal", "hotswap", "multitenant")
 
 def serving_graph(model: str = "vgg19", full: bool = False) -> LayerGraph:
     """Reduced: stacks CPU tests can serve in seconds. Full: the real
-    network depth (VGG at reduced resolution — the CPU budget; 96 is the
-    largest such size whose five pooling stages all tile exactly, where the
-    old 112 relied on the silent 7 -> 3 truncation PoolSpec now rejects)."""
+    network depth and conv widths (VGG at 96x96 with a 512-wide head: the
+    published 224x224 maps do not fit the full-map VMEM tiles yet; 96 is
+    the largest size below 112 whose five pooling stages all tile exactly,
+    where 112 relied on the silent 7 -> 3 truncation PoolSpec rejects)."""
     if model == "lenet":
         from repro.configs.lenet import LENET, LENET_REDUCED
 
@@ -254,11 +259,14 @@ def serve_cnn(*, model: str = "vgg19", full: bool = False,
                  "agreement %.3f, max logit drift %.3g",
                  len(rep8.layers), len(rep8.demoted), rep8.top1_agreement,
                  rep8.max_logit_drift)
-    log.info("%s plan: %s", graph.name, " ".join(
-        f"conv{lp.index + 1}={lp.impl}@{lp.occupancy:.2f}" for lp in engine.plan.layers))
+    plan_line = plan_summary(engine.plan)
+    log.info("%s plan: %s", graph.name, plan_line)
+    t_warm = time.perf_counter()
     compiled = engine.warmup()
-    log.info("warmed %d bucket programs (buckets=%s, devices=%d)", compiled,
-             engine.batcher.exec_buckets(), engine.n_devices)
+    warmup_s = time.perf_counter() - t_warm
+    log.info("warmed %d bucket programs in %.1f s (buckets=%s, devices=%d)",
+             compiled, warmup_s, engine.batcher.exec_buckets(),
+             engine.n_devices)
 
     t_start = clock()
     if scenario == "steady":
@@ -279,6 +287,7 @@ def serve_cnn(*, model: str = "vgg19", full: bool = False,
     summary = {
         "model": graph.name,
         "scenario": scenario,
+        "plan": plan_line,
         "devices": engine.n_devices,
         "prune_density": achieved_density,
         "plan_bsr": stats["plan_bsr"],
@@ -291,8 +300,15 @@ def serve_cnn(*, model: str = "vgg19", full: bool = False,
         "p95_ms": float(np.percentile(lat_ms, 95)),
         "mean_fill": stats["mean_fill"],
         **{k: stats[k] for k in ("batches", "compiles", "hits", "replans",
-                                 "hot_swaps")},
+                                 "hot_swaps", "replan_errors",
+                                 "verify_rejects")},
+        "warmup_s": warmup_s,
         "calibrated": 0 if calibration is None else len(calibration.entries),
+        # the steady stream's logits in request order (other scenarios mix
+        # tenants and streams)
+        "logits": (np.stack([r.logits for r in sorted(results,
+                                                      key=lambda r: r.id)])
+                   if scenario == "steady" else None),
     }
     if tracer is not None:
         tracer.save(trace_out)
@@ -332,20 +348,31 @@ def serve_cnn(*, model: str = "vgg19", full: bool = False,
     return summary
 
 
+def plan_summary(plan) -> str:
+    """One line naming each conv's impl and measured occupancy."""
+    return " ".join(f"conv{lp.index + 1}={lp.impl}@{lp.occupancy:.2f}"
+                    for lp in plan.layers)
+
+
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--model", choices=MODELS, default="vgg19",
                     help="which LayerGraph network to serve")
-    ap.add_argument("--full", action="store_true", help="full network depth (slow on CPU)")
+    ap.add_argument("--full", action="store_true",
+                    help="full network depth and widths (slow on CPU)")
     ap.add_argument("--n-requests", type=int, default=24)
     ap.add_argument("--rate", type=float, default=50.0, help="offered request rate (req/s)")
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--deadline-ms", type=float, default=10.0)
     ap.add_argument("--occ-threshold", type=float, default=0.75)
     ap.add_argument("--block-c", type=int, default=8,
-                    help="channel-block size (0 = auto; auto picks one block "
-                         "for the reduced net's 16 channels, so 8 by default)")
+                    help="channel-block size (0 = auto: 128 channels, or one "
+                         "block for a narrower layer — a single block for "
+                         "the reduced net's 16 channels, so 8 by default)")
     ap.add_argument("--replan-band", type=float, default=0.15)
     ap.add_argument("--autotune", action="store_true")
     ap.add_argument("--devices", type=int, default=0,

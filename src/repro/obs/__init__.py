@@ -49,10 +49,11 @@ from repro.obs.history import (
     telemetry_rows,
 )
 from repro.obs.constants import (
-    DEFAULT_HBM_BW,
-    DEFAULT_PEAK_FLOPS,
-    DEFAULT_ROOFLINE,
+    CPU_TEST_PRIOR,
+    DEVICE_PEAKS,
     RooflineConstants,
+    device_peaks,
+    peaks_for,
 )
 from repro.obs.profile import (
     PROFILE_IMPLS,
@@ -76,9 +77,8 @@ __all__ = [
     "BenchDB",
     "CalibEntry",
     "CalibrationDB",
-    "DEFAULT_HBM_BW",
-    "DEFAULT_PEAK_FLOPS",
-    "DEFAULT_ROOFLINE",
+    "CPU_TEST_PRIOR",
+    "DEVICE_PEAKS",
     "LayerTileSearch",
     "LayerTiming",
     "NULL_TRACER",
@@ -86,6 +86,8 @@ __all__ = [
     "PROFILE_IMPLS",
     "ProfileReport",
     "RooflineConstants",
+    "device_peaks",
+    "peaks_for",
     "Thresholds",
     "TileCandidate",
     "TileSearchReport",

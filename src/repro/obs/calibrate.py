@@ -44,7 +44,7 @@ from __future__ import annotations
 import json
 from dataclasses import asdict, dataclass
 
-from repro.obs.constants import DEFAULT_ROOFLINE, RooflineConstants
+from repro.obs.constants import RooflineConstants, device_peaks
 
 
 def device_kind() -> str:
@@ -153,10 +153,11 @@ class CalibrationDB:
 
     def constants_for(self, kind: str, impl: str, block_c: int = 0,
                       device: str | None = None, tile=None) -> RooflineConstants:
-        """The effective constants for a key: calibrated, else the defaults
-        (the one resolution rule every modeled time goes through)."""
+        """The effective constants for a key: calibrated, else the device's
+        published peaks (the one resolution rule every modeled time goes
+        through)."""
         return self.lookup(kind, impl, block_c, device, tile=tile) \
-            or DEFAULT_ROOFLINE
+            or device_peaks()
 
     # -- tile-search winners ---------------------------------------------------
 
@@ -193,6 +194,7 @@ class CalibrationDB:
         """Fold a `ProfileReport` in: one entry per (kind, impl, geometry)
         group, scale = median(predicted_default / measured) (see module
         docstring). Returns self (chainable)."""
+        peaks = device_peaks()
         for (kind, impl), rows in report.by_impl().items():
             by_tk: dict = {}
             for t in rows:
@@ -206,8 +208,8 @@ class CalibrationDB:
                     continue  # degenerate measurement; keep the defaults
                 spread = (ratios[-1] - ratios[0]) / max(s, 1e-12)
                 self.entries[(report.device_kind, kind, impl, tk)] = CalibEntry(
-                    peak_flops=DEFAULT_ROOFLINE.peak_flops * s,
-                    hbm_bw=DEFAULT_ROOFLINE.hbm_bw * s,
+                    peak_flops=peaks.peak_flops * s,
+                    hbm_bw=peaks.hbm_bw * s,
                     scale=float(s), n_samples=len(grp),
                     resid_spread=float(spread))
         if self.device is None:

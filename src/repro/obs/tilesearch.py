@@ -169,8 +169,7 @@ def search_layer(unit, w, x, kind: str, impl: str, *, iters: int = 2,
     """Search one layer's tile geometry at its planned (kind, impl).
 
     x is the layer's REAL input (the dense-oracle walk of `tile_search`), so
-    occupancy — re-measured per candidate block_c, at the impl's operand
-    width — prices exactly the schedule each geometry would run. Candidates
+    occupancy — re-measured per candidate block_c — prices exactly the schedule each geometry would run. Candidates
     whose modeled time exceeds `prune_factor` x the modeled minimum are not
     timed (the roofline prune); of the rest the `max_timed` modeled-best are
     (the default always is). Winner rule: see module docstring.
@@ -198,8 +197,7 @@ def search_layer(unit, w, x, kind: str, impl: str, *, iters: int = 2,
                                shape_key=shape_key, best=cand, default=cand,
                                candidates=(cand,))
 
-    dtype_bytes = 1 if op.quantized else 4
-    c, h, wdt = unit.in_shape
+    c = unit.in_shape[0]
     conv = unit.conv
     k_taps = c * conv.k * conv.k
     wm = conv_weight_matrix(w) if op.weight_sparse else None
@@ -209,7 +207,7 @@ def search_layer(unit, w, x, kind: str, impl: str, *, iters: int = 2,
         occ = 1.0
         wd = 1.0
         if op.sparse:
-            occ = measure_occupancy(x, tile=t, dtype_bytes=dtype_bytes)
+            occ = measure_occupancy(x, tile=t)
         if op.weight_sparse:
             from repro.kernels.tiles import resolve_bsr_tile
 
@@ -274,7 +272,7 @@ def tile_search(plan, params, calib, *, iters: int = 2, warmup: int = 1,
     from repro.graph.executor import run_unit
     from repro.graph.ir import graph_weights
     from repro.obs.calibrate import CalibrationDB
-    from repro.obs.constants import DEFAULT_ROOFLINE
+    from repro.obs.constants import device_peaks
     from repro.obs.trace import NULL_TRACER
 
     tracer = tracer or NULL_TRACER
@@ -309,6 +307,7 @@ def tile_search(plan, params, calib, *, iters: int = 2, warmup: int = 1,
         # ratio against an already-calibrated model would double-apply scales.
         from repro.obs.calibrate import CalibEntry, _median
 
+        peaks = device_peaks()
         ratios: dict = {}
         for r in rows:
             for cd in r.candidates:
@@ -321,8 +320,8 @@ def tile_search(plan, params, calib, *, iters: int = 2, warmup: int = 1,
             if s <= 0.0:
                 continue
             db.put(kind, impl, 0, CalibEntry(
-                peak_flops=DEFAULT_ROOFLINE.peak_flops * s,
-                hbm_bw=DEFAULT_ROOFLINE.hbm_bw * s, scale=float(s),
+                peak_flops=peaks.peak_flops * s,
+                hbm_bw=peaks.hbm_bw * s, scale=float(s),
                 n_samples=len(rs),
                 resid_spread=float((rs[-1] - rs[0]) / max(s, 1e-12))),
                 tile=TileConfig.from_key(tkey))

@@ -77,7 +77,7 @@ class LayerPlan:
 class PipelinePlan:
     layers: tuple  # tuple[LayerPlan, ...]
     occ_threshold: float
-    block_c: int  # 0 = auto per layer (ops._pick_block_c)
+    block_c: int  # 0 = auto per layer (tiles.resolve_block_c)
     graph: LayerGraph | None = None  # the IR the plan was made for
     int8_report: object = None  # quant.Int8Report when int8 planning probed
 
@@ -98,8 +98,7 @@ class PipelinePlan:
         return c
 
 
-def occupancy_stat(x, block_c: int = 0, n_valid=None, tile=None,
-                   dtype_bytes: int = 4):
+def occupancy_stat(x, block_c: int = 0, n_valid=None, tile=None):
     """Traced (jit-safe) channel-block occupancy, measured the way the batched
     kernel schedules: shared-union channel compaction, then PER-SAMPLE block
     occupancy on the packed layout (== mean_b cnt_b / n_cb of
@@ -122,9 +121,9 @@ def occupancy_stat(x, block_c: int = 0, n_valid=None, tile=None,
     """
     if x.ndim == 3:
         x = x[None]
-    n, c, h, w = x.shape
+    n, c = x.shape[:2]
     t = tile if tile is not None and tile else TileConfig(block_c=block_c)
-    bc = resolve_block_c(h, w, c, t, dtype_bytes)
+    bc = resolve_block_c(c, t)
     n_cb = -(-c // bc)
     live = jnp.any(x != 0, axis=(2, 3))  # (N, C) per-sample live channels
     if n_valid is not None:
@@ -140,10 +139,9 @@ def occupancy_stat(x, block_c: int = 0, n_valid=None, tile=None,
     return jnp.where(jnp.arange(n) < nv, per_sample, 0.0).sum() / jnp.maximum(nv, 1)
 
 
-def measure_occupancy(x, block_c: int = 0, tile=None,
-                      dtype_bytes: int = 4) -> float:
+def measure_occupancy(x, block_c: int = 0, tile=None) -> float:
     """Concrete-value wrapper of `occupancy_stat` (see its docstring)."""
-    return float(occupancy_stat(x, block_c, tile=tile, dtype_bytes=dtype_bytes))
+    return float(occupancy_stat(x, block_c, tile=tile))
 
 
 def plan_network(
@@ -202,8 +200,7 @@ def plan_network(
     `int8=True` adds the PRECISION axis: a layer placed on a Pallas sparse or
     BSR impl is upgraded to its int8 sibling (`ecr_int8` / `bsr_int8`) iff
     the quantized roofline time wins — with occupancy re-measured at the
-    int8 geometry (dtype_bytes=1 fits 4x wider channel blocks) and the int8
-    impl's own stored tile winner. Because quantization trades accuracy, the
+    int8 impl's own stored tile winner. Because quantization trades accuracy, the
     upgrades are then PROBED: planned logits vs the dense fp32 oracle on the
     calibration batch, and int8 layers are demoted back to their fp32 choice
     (least modeled saving first) until top-1 agreement >= `int8_budget`.
@@ -273,9 +270,8 @@ def plan_network(
                     if tiles is not None else None
                 q_occ = occ
                 if get_op("conv", q_impl).sparse:
-                    # int8 operands fit 4x wider channel blocks per VMEM
-                    q_occ = measure_occupancy(x, block_c, tile=q_tile,
-                                              dtype_bytes=1)
+                    # the stat must describe the int8 winner's schedule
+                    q_occ = measure_occupancy(x, block_c, tile=q_tile)
                 base_us = unit_model_us(kind, impl, unit, occupancy=occ,
                                         weight_density=wd, batch=batch,
                                         block_c=block_c, tile=tile,
@@ -474,7 +470,7 @@ def run_plan_sharded(plan: PipelinePlan, params, imgs, mesh, *,
     buckets guarantee it, and anything else raises here rather than silently
     replicating.
     """
-    from jax.experimental.shard_map import shard_map
+    import jax
     from jax.sharding import PartitionSpec as P
 
     if imgs.ndim == 3:
@@ -496,8 +492,6 @@ def run_plan_sharded(plan: PipelinePlan, params, imgs, mesh, *,
     local_n = n // n_dev
 
     if collect_occupancy:
-        import jax
-
         nv = jnp.asarray(n if n_valid is None else n_valid, jnp.int32)
 
         def mapped(params, imgs_local, nv):
@@ -506,13 +500,13 @@ def run_plan_sharded(plan: PipelinePlan, params, imgs, mesh, *,
             return run_plan(plan, params, imgs_local, collect_occupancy=True,
                             n_valid=nv_local, axis_name="data")
 
-        fn = shard_map(mapped, mesh=mesh, in_specs=(P(), P("data"), P()),
-                       out_specs=(P("data"), P()), check_rep=False)
+        fn = jax.shard_map(mapped, mesh=mesh, in_specs=(P(), P("data"), P()),
+                           out_specs=(P("data"), P()), check_vma=False)
         return fn(params, imgs, nv)
 
     def mapped(params, imgs_local):
         return run_plan(plan, params, imgs_local)
 
-    fn = shard_map(mapped, mesh=mesh, in_specs=(P(), P("data")),
-                   out_specs=P("data"), check_rep=False)
+    fn = jax.shard_map(mapped, mesh=mesh, in_specs=(P(), P("data")),
+                       out_specs=P("data"), check_vma=False)
     return fn(params, imgs)

@@ -3,10 +3,10 @@
 `ecr_conv_int8` / `conv2d_bsr_int8` mirror their fp32 siblings
 (`kernels.ecr_conv.ops.ecr_conv`, `sparse_weights.conv.conv2d_bsr`) exactly —
 same compaction, same schedules, same tile-geometry resolution through
-`repro.kernels.tiles` (with dtype_bytes=1: int8 activations fit 4x wider
-channel blocks in the same VMEM budget) — and differ only in precision:
-operands are absmax-int8 (`repro.quant.quantize`), the MAC accumulates
-int32, and the flush rescales to fp32. In/out dtypes are fp32 like every
+`repro.kernels.tiles`, and the same Pallas kernels (`conv_pallas`,
+`bsr_matmul_pallas` with scales) — and differ only in precision: operands
+are absmax-int8 (`repro.quant.quantize`), the MAC accumulates int32, and the
+flush rescales to fp32. In/out dtypes are fp32 like every
 registry forward, so the planner can swap an int8 impl into any layer
 without touching its neighbors.
 
@@ -30,13 +30,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.bsr_matmul.kernel import bsr_matmul_pallas
 from repro.kernels.schedule_guard import guard_schedule
 from repro.kernels.tiles import BsrLaunch, ConvLaunch, TileConfig
-from repro.quant.kernels import (
-    bsr_matmul_int8_pallas,
-    ecr_conv_int8_pallas,
-    ecr_conv_int8_pallas_batch,
-)
 from repro.quant.quantize import quantize_acts, quantize_weights
 
 
@@ -58,9 +54,9 @@ def ecr_conv_int8_launch(c: int, h: int, w: int, o: int, kh: int = 3,
                          block_o: int = 0, tile: TileConfig | None = None,
                          batch: int = 1) -> ConvLaunch:
     """`ConvLaunch` of one int8 ECR conv call: the fp32 builder at
-    dtype_bytes=1 (int8 activations fit 4x wider channel blocks in the same
-    VMEM budget) with the int8 contract recorded — int32 accumulation,
-    per-output-channel weight scales — for the static checker to verify."""
+    dtype_bytes=1 (a quarter of the operand VMEM) with the int8 contract
+    recorded — int32 accumulation, per-output-channel weight scales — for
+    the static checker to verify."""
     from repro.kernels.ecr_conv.ops import ecr_conv_launch
 
     return ecr_conv_launch(c, h, w, o, kh, kw, stride=stride, block_c=block_c,
@@ -81,71 +77,31 @@ def bsr_conv_int8_launch(o: int, k_taps: int, p: int, *,
                            weight_scales="per_output_channel")
 
 
-@partial(jax.jit, static_argnames=("stride", "interpret", "block_c",
-                                   "block_o", "compact"))
-def ecr_conv_int8(x_chw, kernels_oihw, stride: int = 1, interpret: bool = True,
-                  block_c: int = 0, block_o: int = 0, compact: bool = True):
+@partial(jax.jit, static_argnames=("stride", "block_c", "block_o", "compact"))
+def ecr_conv_int8(x_chw, kernels_oihw, stride: int = 1, block_c: int = 0,
+                  block_o: int = 0, compact: bool = True):
     """int8 ECR conv: (C,H,W) x (O,C,kh,kw) -> fp32 (O,oh,ow), skipping dead
     input channel blocks; batched (N,C,H,W) -> (N,O,oh,ow) with per-sample
-    schedules AND per-sample activation scales. Quantization happens after
-    channel compaction (compaction only permutes channels, so scales are
-    invariant to it) and the block schedule is computed on the QUANTIZED
-    values — a block that rounds to all-zero is skipped, which is exact
-    (its dequantized contribution would be zero)."""
-    from repro.core.ecr import compact_live_channels, compact_live_channels_batch
-    from repro.core.sparsity import block_occupancy, compact_block_ids
-    from repro.kernels.ecr_conv.ops import batch_block_schedule
+    schedules AND per-sample activation scales (one image runs as a batch
+    of one, so its scale is per tensor). Quantization happens after channel
+    compaction (compaction only permutes channels, so scales are invariant
+    to it) and the block schedule is computed on the QUANTIZED values — a
+    block that rounds to all-zero is skipped, which is exact (its
+    dequantized contribution would be zero)."""
+    from repro.core.ecr import compact_live_channels_batch
+    from repro.kernels.ecr_conv.ops import as_conv_operands, run_conv_kernel
 
-    if x_chw.ndim == 2:
-        x_chw = x_chw[None]
-    if kernels_oihw.ndim == 3:
-        kernels_oihw = kernels_oihw[None]
-    batched = x_chw.ndim == 4
-    c, h, w = x_chw.shape[-3:]
-    o, c2, kh, kw = kernels_oihw.shape
+    x, kernels_oihw, single = as_conv_operands(x_chw, kernels_oihw)
+    n, c, h, w = x.shape
+    o, _, kh, kw = kernels_oihw.shape
     launch = ecr_conv_int8_launch(c, h, w, o, kh, kw, stride=stride,
-                                  block_c=block_c, block_o=block_o,
-                                  batch=x_chw.shape[0] if batched else 1)
-    bc, bo = launch.block_c, launch.block_o
-    cp, op, n_cb = launch.c_pad, launch.o_pad, launch.n_cb
-
-    if batched:
-        assert x_chw.shape[0] > 0, "empty batch: ecr_conv_int8 needs N >= 1"
-        if compact:
-            x_chw, kernels_oihw, _ = compact_live_channels_batch(x_chw, kernels_oihw)
-        xq, sx = quantize_acts(x_chw, per_sample=True)  # (N,C,H,W) i8, (N,)
-        wq, sw = quantize_weights(kernels_oihw)  # (O,C,kh,kw) i8, (O,)
-        x = jnp.pad(xq, ((0, 0), (0, cp), (0, 0), (0, 0))).transpose(0, 2, 3, 1)
-        wk = jnp.pad(wq, ((0, op), (0, cp), (0, 0), (0, 0))).transpose(2, 3, 1, 0)
-        ids, cnt = batch_block_schedule(x, h, w, bc)
-        ids, cnt = guard_schedule(ids, cnt, n_cb)
-        out = ecr_conv_int8_pallas_batch(
-            x, wk, sx[:, None], jnp.pad(sw, (0, op), constant_values=1.0)[None],
-            ids, cnt, stride=stride, block_c=bc, block_o=bo,
-            interpret=interpret,
-        )
-        return out.transpose(0, 3, 1, 2)[:, :o]
-
+                                  block_c=block_c, block_o=block_o, batch=n)
     if compact:
-        x_chw, kernels_oihw, n_live = compact_live_channels(x_chw, kernels_oihw)
-    xq, sx = quantize_acts(x_chw)
-    wq, sw = quantize_weights(kernels_oihw)
-    x = jnp.pad(xq, ((0, cp), (0, 0), (0, 0))).transpose(1, 2, 0)  # (H,W,C')
-    wk = jnp.pad(wq, ((0, op), (0, cp), (0, 0), (0, 0))).transpose(2, 3, 1, 0)
-    if compact:
-        ids = jnp.arange(n_cb, dtype=jnp.int32)  # identity: prefix is live
-        cnt = jnp.minimum((n_live + bc - 1) // bc, n_cb).astype(jnp.int32)
-    else:
-        occ = block_occupancy(x, (h, w, bc)).reshape(-1)
-        ids, cnt = compact_block_ids(occ)
-    ids, cnt = guard_schedule(ids, cnt, n_cb)
-    out = ecr_conv_int8_pallas(
-        x, wk, sx.reshape(1, 1),
-        jnp.pad(sw, (0, op), constant_values=1.0)[None],
-        ids, cnt[None], stride=stride, block_c=bc, block_o=bo,
-        interpret=interpret,
-    )
-    return out.transpose(2, 0, 1)[:o]
+        x, kernels_oihw, _ = compact_live_channels_batch(x, kernels_oihw)
+    xq, sx = quantize_acts(x, per_sample=True)  # (N,C,H,W) i8, (N,)
+    wq, sw = quantize_weights(kernels_oihw)  # (O,C,kh,kw) i8, (O,)
+    y = run_conv_kernel(xq, wq, launch, sx=sx, sw=sw)
+    return y[0] if single else y
 
 
 def ecr_conv_int8_ref(x, w, stride: int = 1):
@@ -164,8 +120,8 @@ def ecr_conv_int8_ref(x, w, stride: int = 1):
     return y * sx * sw[:, None, None]
 
 
-@partial(jax.jit, static_argnames=("stride", "interpret", "tile"))
-def conv2d_bsr_int8(x, w, stride: int = 1, interpret: bool = True, tile=None):
+@partial(jax.jit, static_argnames=("stride", "tile"))
+def conv2d_bsr_int8(x, w, stride: int = 1, tile=None):
     """int8 weight-block-sparse conv: the `conv2d_bsr` im2col lowering with
     the quantized weight matrix as the sparse left operand. Weights carry one
     scale per output channel (= per row of W:(O,K), delivered as (bt, 1)
@@ -199,8 +155,8 @@ def conv2d_bsr_int8(x, w, stride: int = 1, interpret: bool = True, tile=None):
     sw_p = jnp.pad(sw, (0, launch.t_pad), constant_values=1.0)[:, None]  # (Op,1)
     ids, cnt = block_schedule(wm_p, bt, bf)
     ids, cnt = guard_schedule(ids, cnt, launch.nf)
-    yt = bsr_matmul_int8_pallas(wm_p, at_p, sw_p, sa.reshape(1, 1), ids, cnt,
-                                block=(bt, bf, bd), interpret=interpret)
+    yt = bsr_matmul_pallas(wm_p, at_p, ids, cnt, block=(bt, bf, bd),
+                           sh=sw_p, sw=sa.reshape(1, 1))
     y = yt[:o, :p].T.reshape(n, oh, ow, o).transpose(0, 3, 1, 2)
     return y[0] if single else y
 

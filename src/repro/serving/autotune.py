@@ -25,7 +25,8 @@ from dataclasses import dataclass, field
 import jax
 
 from repro.graph import as_graph
-from repro.graph.registry import HBM_BW, PEAK_FLOPS, get_op, unit_model_us
+from repro.graph.registry import get_op, unit_model_us
+from repro.obs.constants import device_peaks
 from repro.pipeline.planner import PipelinePlan, plan_network, run_plan, run_plan_sharded
 from repro.serving.plan_cache import plan_key
 
@@ -94,7 +95,7 @@ def plan_model_us(plan: PipelinePlan, params, batch: int = 1,
         d_in, d_out = w.shape
         flops += 2.0 * batch * d_in * d_out
         nbytes += 4.0 * (d_in * d_out + batch * (d_in + d_out))
-    return us + max(flops / PEAK_FLOPS, nbytes / HBM_BW) * 1e6
+    return us + device_peaks().time_us(flops, nbytes)
 
 
 def hlo_model_us(fn, *args) -> float:
@@ -104,7 +105,7 @@ def hlo_model_us(fn, *args) -> float:
 
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     a = hlo_cost.analyze(hlo)
-    return max(a["flops"] / PEAK_FLOPS, a["bytes"] / HBM_BW) * 1e6
+    return device_peaks().time_us(a["flops"], a["bytes"])
 
 
 def _time_us(f, *args, iters: int = 3, warmup: int = 1) -> tuple:

@@ -22,7 +22,11 @@
 Exactness contract: a request's logits are bit-identical to `run_plan` on the
 same image(s) whenever the co-batched samples share a live-channel union (the
 shared-union compaction permutation is then batch-composition-invariant); the
-all-zero pad samples never perturb the union. tests/test_serving.py pins this.
+all-zero pad samples never perturb the union. tests/test_serving.py pins this
+on the CPU. On a TPU at default matmul precision the result also depends on
+the batch an image runs in: on a v5e, VGG-19 `--full` logits served one image
+per bucket differ from `run_plan` over all 16 images by 5.4e-3 of the
+largest logit (`chip_smoke.py --four-chips` holds the sharded path to 1e-2).
 """
 from __future__ import annotations
 

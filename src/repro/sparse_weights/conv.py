@@ -62,8 +62,8 @@ def conv2d_bsr_ref(x, w, stride: int = 1):
     return conv2d_dense(x, w, stride)
 
 
-@partial(jax.jit, static_argnames=("stride", "interpret", "tile"))
-def conv2d_bsr(x, w, stride: int = 1, interpret: bool = True, tile=None):
+@partial(jax.jit, static_argnames=("stride", "tile"))
+def conv2d_bsr(x, w, stride: int = 1, tile=None):
     """Weight-block-sparse conv. x: (C,H,W) or (N,C,H,W) already padded
     (VALID semantics, like every registry conv forward); w: (O,C,kh,kw).
     Returns float32 (O,oh,ow) / (N,O,oh,ow).
@@ -97,8 +97,8 @@ def conv2d_bsr(x, w, stride: int = 1, interpret: bool = True, tile=None):
     at_p = jnp.pad(a, ((0, launch.d_pad), (0, launch.f_pad))).T  # (Kp, Pp)
     ids, cnt = block_schedule(wm_p, bt, bf)
     ids, cnt = guard_schedule(ids, cnt, launch.nf)
-    yt = bsr_matmul_pallas(wm_p, at_p, ids, cnt, block=(bt, bf, bd),
-                           interpret=interpret)  # (Op, Pp) = y^T
+    yt = bsr_matmul_pallas(wm_p, at_p, ids, cnt,
+                           block=(bt, bf, bd))  # (Op, Pp) = y^T
     y = yt[:o, :p].T.reshape(n, oh, ow, o).transpose(0, 3, 1, 2)
     return y[0] if single else y
 

@@ -126,10 +126,10 @@ def test_rpa103_vmem_budget():
     diags = check_launch_descriptor(big)
     assert [d.code for d in diags] == ["RPA103"]
     assert diags[0].severity == "warn"
-    # an explicitly requested oversized tile is an ERROR: the default
-    # policy would have shrunk it, so only a request can get here
-    big = ecr_conv_launch(128, 512, 512, 128,
-                          tile=TileConfig(block_c=128))
+    # an explicitly requested tile above the default blocks is an ERROR:
+    # the default policy would not have chosen it
+    big = ecr_conv_launch(256, 512, 512, 128,
+                          tile=TileConfig(block_c=256))
     diags = check_launch_descriptor(big)
     assert [d.code for d in diags] == ["RPA103"]
     assert diags[0].severity == "error"

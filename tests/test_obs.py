@@ -16,7 +16,7 @@ from repro.core import dead_channel_band
 from repro.graph import init_graph
 from repro.models.cnn import shift_dead_channels
 from repro.obs import (
-    DEFAULT_ROOFLINE,
+    CPU_TEST_PRIOR,
     NULL_TRACER,
     CalibEntry,
     CalibrationDB,
@@ -209,12 +209,12 @@ def test_calibration_fit_and_lookup():
     db = CalibrationDB.from_report(report)
     # dense: ratio 0.1 on both layers -> scale 0.1
     c = db.lookup("conv", "dense", 8, device="testdev")
-    assert c.peak_flops == pytest.approx(DEFAULT_ROOFLINE.peak_flops * 0.1)
-    assert c.hbm_bw == pytest.approx(DEFAULT_ROOFLINE.hbm_bw * 0.1)
+    assert c.peak_flops == pytest.approx(CPU_TEST_PRIOR.peak_flops * 0.1)
+    assert c.hbm_bw == pytest.approx(CPU_TEST_PRIOR.hbm_bw * 0.1)
     # scaled constants predict the measured time for the fitted rows
     t = report.timings[0]
     assert c.time_us(t.flops, t.bytes) == pytest.approx(
-        DEFAULT_ROOFLINE.time_us(t.flops, t.bytes) / 0.1)
+        CPU_TEST_PRIOR.time_us(t.flops, t.bytes) / 0.1)
     assert db.covers("conv", "ecr_pallas", 8, device="testdev")
     assert not db.covers("conv", "bsr", 8, device="testdev")
     # block_c fallback: an explicit geometry falls back to the bc=0 entry
@@ -241,7 +241,7 @@ def test_calibration_save_load_roundtrip(tmp_path):
 def test_empty_db_is_falsy_and_defaults():
     db = CalibrationDB(device="testdev")
     assert not db and len(db) == 0
-    assert db.constants_for("conv", "dense", 8) is DEFAULT_ROOFLINE
+    assert db.constants_for("conv", "dense", 8) is CPU_TEST_PRIOR
 
 
 def test_report_agreement_and_recalibration():
@@ -283,10 +283,10 @@ def test_calibration_shift_flips_impl_choice(graph, params, calib):
     # flip those layers to dense
     dev = device_kind()
     db = CalibrationDB(device=dev)
-    slow = CalibEntry(DEFAULT_ROOFLINE.peak_flops * 1e-6,
-                      DEFAULT_ROOFLINE.hbm_bw * 1e-6, 1e-6, 2, 0.0)
-    fast = CalibEntry(DEFAULT_ROOFLINE.peak_flops,
-                      DEFAULT_ROOFLINE.hbm_bw, 1.0, 2, 0.0)
+    slow = CalibEntry(CPU_TEST_PRIOR.peak_flops * 1e-6,
+                      CPU_TEST_PRIOR.hbm_bw * 1e-6, 1e-6, 2, 0.0)
+    fast = CalibEntry(CPU_TEST_PRIOR.peak_flops,
+                      CPU_TEST_PRIOR.hbm_bw, 1.0, 2, 0.0)
     for kind, impl in (("conv", "ecr_pallas"), ("conv_pool", "pecr_pallas"),
                        ("conv_pool", "ecr_pallas")):
         db.put(kind, impl, 8, slow, device=dev)

@@ -429,15 +429,14 @@ def test_explicit_block_c_override_honored_end_to_end(params, monkeypatch):
     seen = []
     real_ecr, real_fused = ecr_ops.ecr_conv, cp_ops.fused_conv_pool
 
-    def spy_ecr(x, w, stride=1, interpret=True, block_c=0, **kw):
+    def spy_ecr(x, w, stride=1, block_c=0, **kw):
         seen.append(("ecr", block_c))
-        return real_ecr(x, w, stride=stride, interpret=interpret,
-                        block_c=block_c, **kw)
+        return real_ecr(x, w, stride=stride, block_c=block_c, **kw)
 
-    def spy_fused(x, w, stride=1, pool=2, p_s=None, interpret=True, block_c=0, **kw):
+    def spy_fused(x, w, stride=1, pool=2, p_s=None, block_c=0, **kw):
         seen.append(("pecr", block_c))
         return real_fused(x, w, stride=stride, pool=pool, p_s=p_s,
-                          interpret=interpret, block_c=block_c, **kw)
+                          block_c=block_c, **kw)
 
     monkeypatch.setattr(ecr_ops, "ecr_conv", spy_ecr)
     monkeypatch.setattr(cp_ops, "fused_conv_pool", spy_fused)

@@ -18,11 +18,12 @@ from repro.kernels.tiles import (
     DEFAULT_TILE,
     TileConfig,
     as_tile,
-    pick_block_c,
     resolve_block_c,
     resolve_bsr_tile,
     resolve_conv_tile,
 )
+from repro.kernels.ecr_conv.ops import ecr_conv_launch
+from repro.quant.ops import ecr_conv_int8_launch
 from repro.pipeline.planner import occupancy_stat
 from repro.sparse_weights import conv2d_bsr, conv2d_bsr_ref, prune_matrix, weight_block
 from repro.sparse_weights.format import conv_weight_matrix
@@ -56,31 +57,37 @@ def test_as_tile_precedence():
 
 def test_resolve_block_c_honors_conforming_and_rejects_oversized():
     # conforming: 0 < bc <= max(8, c) honored EXACTLY, even non-dividing
-    assert resolve_block_c(12, 12, 16, TileConfig(block_c=12)) == 12
-    assert resolve_block_c(12, 12, 16, TileConfig(block_c=16)) == 16
+    assert resolve_block_c(16, TileConfig(block_c=12)) == 12
+    assert resolve_block_c(16, TileConfig(block_c=16)) == 16
     # oversized / non-positive -> the default policy, independently
-    auto = resolve_block_c(12, 12, 16, None)
-    assert resolve_block_c(12, 12, 16, TileConfig(block_c=256)) == auto
-    assert resolve_block_c(12, 12, 16, TileConfig()) == auto
+    auto = resolve_block_c(16, None)
+    assert resolve_block_c(16, TileConfig(block_c=256)) == auto
+    assert resolve_block_c(16, TileConfig()) == auto
     # small c: bc request up to max(8, c) still honored
-    assert resolve_block_c(4, 4, 3, TileConfig(block_c=8)) == 8
+    assert resolve_block_c(3, TileConfig(block_c=8)) == 8
+    # the default is one lane width of channels, or one block of a narrow layer
+    assert resolve_block_c(512, None) == 128
+    assert resolve_block_c(64, None) == 64
 
 
 def test_resolve_block_c_dtype_bytes_widens_int8():
-    # at a spatial size where fp32 halves the block, int8 fits 4x channels
-    h = w = 512  # 512*512*128*4 = 128MB >> budget; shrinks fp32's pick
-    bc_f32 = resolve_block_c(h, w, 256, None, dtype_bytes=4)
-    bc_i8 = resolve_block_c(h, w, 256, None, dtype_bytes=1)
-    assert bc_i8 == min(4 * bc_f32, 128)
-    assert pick_block_c(h, w, 256, dtype_bytes=1) == 4 * pick_block_c(h, w, 256)
+    # a block narrower than 128 channels takes the same lane-padded VMEM, so
+    # neither width shrinks at a huge map (the old fp32 pick halved here);
+    # int8 operands take a quarter of the operand VMEM at the same blocks
+    h = w = 512
+    f32 = ecr_conv_launch(256, h, w, 256)
+    i8 = ecr_conv_int8_launch(256, h, w, 256)
+    assert f32.block_c == i8.block_c == resolve_block_c(256, None) == 128
+    assert 4 * h * w * 128 < f32.vmem_bytes
+    assert i8.vmem_bytes < f32.vmem_bytes
 
 
 def test_resolve_conv_tile_bo_clamp():
-    bc, bo = resolve_conv_tile(12, 12, 16, 24, TileConfig(block_c=8, block_o=8))
+    bc, bo = resolve_conv_tile(16, 24, TileConfig(block_c=8, block_o=8))
     assert (bc, bo) == (8, 8)
     # default bo = min(128, max(8, o)); an oversized request clamps the same
-    assert resolve_conv_tile(12, 12, 16, 24, None)[1] == 24
-    assert resolve_conv_tile(12, 12, 16, 24, TileConfig(block_o=999))[1] == 24
+    assert resolve_conv_tile(16, 24, None)[1] == 24
+    assert resolve_conv_tile(16, 24, TileConfig(block_o=999))[1] == 24
 
 
 def test_resolve_bsr_tile_per_dim_independent_fallback():
@@ -173,7 +180,7 @@ def test_channel_block_occupancy_matches_executed_schedule(block_c):
     c, h, w = 16, 10, 10
     x = _fm((c, h, w), 0.0, seed=9)
     x = x.at[5:].set(0.0)  # 5 live channels
-    bc = resolve_conv_tile(h, w, c, c, TileConfig(block_c=block_c))[0]
+    bc = resolve_conv_tile(c, c, TileConfig(block_c=block_c))[0]
     n_cb = math.ceil(c / bc)
     expect = math.ceil(5 / bc) / n_cb
     got = channel_block_occupancy(x, block_c=block_c, compact=True)
@@ -196,9 +203,11 @@ def test_occupancy_stat_tile_beats_legacy_block_c():
 
 
 def test_occupancy_stat_int8_geometry():
-    # dtype_bytes=1 resolves the auto pick 4x wider only when VMEM binds;
-    # with an explicit conforming block the two widths agree exactly
+    # int8 and fp32 launches resolve the same channel block, so the stat
+    # measured at either geometry agrees exactly
     x = _fm((16, 10, 10), 0.0, seed=11).at[5:].set(0.0)
-    a = float(occupancy_stat(x[None], 8, dtype_bytes=4))
-    b = float(occupancy_stat(x[None], 8, dtype_bytes=1))
-    assert a == b
+    f32 = ecr_conv_launch(16, 12, 12, 16, block_c=8)
+    i8 = ecr_conv_int8_launch(16, 12, 12, 16, block_c=8)
+    a = float(occupancy_stat(x[None], f32.block_c))
+    b = float(occupancy_stat(x[None], i8.block_c))
+    assert a == b == pytest.approx(1 / 2)
