@@ -101,6 +101,7 @@ def bsr_matmul_pallas(
     y = pallas_call(
         partial(_kernel, nf=nf, scaled=scaled),
         grid_spec=grid_spec,
+        name="bsr_matmul_int8" if scaled else "bsr_matmul",
         out_shape=jax.ShapeDtypeStruct((nt, nd, bt, bd), jnp.float32),
     )(ids, cnt, *operands)
     return y.transpose(0, 2, 1, 3).reshape(t, d)

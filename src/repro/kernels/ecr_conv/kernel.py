@@ -131,10 +131,13 @@ def conv_pallas(
         scratch_shapes=[pltpu.VMEM((oh * ow, bo),
                                    jnp.int32 if scaled else jnp.float32)],
     )
+    # the kernel's name in the compiled program and the profiler's trace
+    name = ("pecr_conv" if pool else "ecr_conv") + ("_int8" if scaled else "")
     return pallas_call(
         partial(_kernel, kh=kh, kw=kw, stride=stride, n_cb=n_cb, oh=oh,
                 ow=ow, pool=pool, scaled=scaled),
         grid_spec=grid_spec,
+        name=name,
         out_shape=jax.ShapeDtypeStruct((n, n_ob, poh, pow_, bo), jnp.float32),
     )(ids, cnt, *operands)
 

@@ -4,8 +4,9 @@ The layer below `serving.metrics` (which aggregates the REQUEST stream):
 this package observes the EXECUTION itself and closes the loop back into
 the planner (DESIGN.md §9):
 
-- `trace`     span tracer (plan -> compile -> per-batch execute ->
-              per-layer kernel), deterministic on a SimClock, exported as
+- `trace`     span tracers: the engine's `serve.*` spans in the profiler's
+              own trace (the default, on the device ops' clock), or
+              recorded on a SimClock deterministically and exported as
               Chrome trace_event JSON loadable in Perfetto;
 - `profile`   the wall-time harness (jit warm-up, block_until_ready,
               median-of-k with outlier rejection — shared with
@@ -71,7 +72,7 @@ from repro.obs.tilesearch import (
     search_layer,
     tile_search,
 )
-from repro.obs.trace import NULL_TRACER, NullTracer, Tracer
+from repro.obs.trace import NULL_TRACER, PROFILER_TRACER, NullTracer, Tracer
 
 __all__ = [
     "BenchDB",
@@ -84,6 +85,7 @@ __all__ = [
     "NULL_TRACER",
     "NullTracer",
     "PROFILE_IMPLS",
+    "PROFILER_TRACER",
     "ProfileReport",
     "RooflineConstants",
     "device_peaks",

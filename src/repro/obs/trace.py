@@ -1,31 +1,38 @@
-"""Span-based tracer: nested spans -> Chrome trace_event JSON (Perfetto).
+"""Span tracers: the profiler's own trace, or nested spans -> Chrome JSON.
 
-The serving stack is a pipeline of phases — plan, compile, per-batch
-execute, per-layer kernel (the profiling harness times each layer
-individually) — and "where did the time go" questions need those phases as
-NESTED intervals on a timeline, not as aggregate counters (which
-`serving.metrics.MetricsTracker` already covers). A `Tracer` records
-complete-duration spans (`ph: "X"` events) against an injectable clock and
-renders them in the Chrome trace_event format, so `trace.json` loads
-directly in Perfetto / chrome://tracing.
+The serving stack is a pipeline of phases (plan, compile, per-request
+submit, per-batch stack / lookup / dispatch / wait / fetch / observe,
+re-plan), and "where did the time go" questions need those phases as NESTED
+intervals on a timeline, not as aggregate counters (which
+`serving.metrics.MetricsTracker` already covers). Three tracers share one
+interface, `span(name, **args)`, a context manager:
 
-Determinism contract (same shape as the MetricsTracker's): the clock is any
-zero-arg callable returning seconds — `time.perf_counter` live, a
-`serving.batcher.SimClock` in replays. Thread ids are LOGICAL (0 for the
-first thread to open a span, 1 for the next, ...), not OS idents, and events
-are appended in span-exit order, so two identical seeded SimClock replays
-produce bit-identical `chrome_trace()` payloads (tests/test_obs.py pins the
-serialized bytes).
-
-Disabled tracing must cost nothing on the serving hot path: `NULL_TRACER`
-(the engine's default) hands back one shared no-op context manager and never
-accumulates state — `span()` allocates nothing.
+- `ProfilerTracer` (the engine's default, `PROFILER_TRACER`): each span is a
+  `jax.profiler.TraceAnnotation`, so it lands in the profiler's own trace,
+  on the same clock as the device's ops. With no profiler running a span
+  costs one TraceMe check; an operator captures them at any time with
+  `jax.profiler.start_trace` / `stop_trace`, or through
+  `jax.profiler.start_server` and a profiler client.
+- `Tracer`: records complete-duration spans (`ph: "X"` events) against an
+  injectable clock and renders them in the Chrome trace_event format, so
+  `trace.json` loads in Perfetto / chrome://tracing. The clock is any
+  zero-arg callable returning seconds: `time.perf_counter` live, a
+  `serving.batcher.SimClock` in replays. Thread ids are LOGICAL (0 for the
+  first thread to open a span, 1 for the next, ...), not OS idents, and
+  events are appended in span-exit order, so two identical seeded SimClock
+  replays produce bit-identical `chrome_trace()` payloads (tests/test_obs.py
+  pins the serialized bytes).
+- `NULL_TRACER`: hands back one shared no-op context manager and never
+  accumulates state; `span()` allocates nothing (the profiling harness and
+  the tile search default to it).
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
+
+import jax
 
 
 class _NullSpan:
@@ -54,9 +61,6 @@ class NullTracer:
     def span(self, name, cat="repro", **args):
         return _NULL_SPAN
 
-    def instant(self, name, cat="repro", **args):
-        return None
-
     def chrome_trace(self) -> dict:
         return {"traceEvents": [], "displayTimeUnit": "ms"}
 
@@ -65,6 +69,20 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
+
+
+class ProfilerTracer:
+    """Spans written into the profiler's own trace (module docstring): each
+    `span(name, **args)` is `jax.profiler.TraceAnnotation(name, **args)`.
+    The trace keeps `name` and the args; `cat` has no place there."""
+
+    __slots__ = ()
+
+    def span(self, name, cat="repro", **args):
+        return jax.profiler.TraceAnnotation(name, **args)
+
+
+PROFILER_TRACER = ProfilerTracer()
 
 
 class _SpanCtx:
@@ -100,9 +118,8 @@ class Tracer:
     """Span recorder over an injectable clock (see module docstring).
 
     `span(name, **args)` is a context manager; spans nest per thread (the
-    depth rides into the event args so nesting survives flat JSON). `instant`
-    marks point events (re-plan triggers, hot swaps). `chrome_trace()` /
-    `save(path)` render the Chrome trace_event JSON.
+    depth rides into the event args so nesting survives flat JSON).
+    `chrome_trace()` / `save(path)` render the Chrome trace_event JSON.
     """
 
     def __init__(self, clock=time.perf_counter, pid: int = 0):
@@ -150,26 +167,6 @@ class Tracer:
 
     def span(self, name: str, cat: str = "repro", **args) -> _SpanCtx:
         return _SpanCtx(self, name, cat, dict(args))
-
-    def instant(self, name: str, cat: str = "repro", **args) -> None:
-        tid = self._tid()  # before the lock: _tid takes it too (non-reentrant)
-        with self._lock:
-            self.events.append({
-                "name": name, "cat": cat, "ph": "i", "s": "t",
-                "ts": self._us(float(self.clock())),
-                "pid": self.pid, "tid": tid, "args": dict(args),
-            })
-
-    def counter(self, name: str, value: float, cat: str = "repro") -> None:
-        """A `ph: "C"` counter sample (Perfetto renders it as a track)."""
-        tid = self._tid()
-        with self._lock:
-            self.events.append({
-                "name": name, "cat": cat, "ph": "C",
-                "ts": self._us(float(self.clock())),
-                "pid": self.pid, "tid": tid,
-                "args": {name: float(value)},
-            })
 
     def chrome_trace(self) -> dict:
         """The Chrome trace_event payload (JSON Object Format)."""

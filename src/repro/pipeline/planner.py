@@ -30,6 +30,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 
 from repro.graph import as_graph
@@ -426,16 +427,21 @@ def run_plan(plan: PipelinePlan, params, imgs, ccfg=None, *,
     occs = []
     for lp, w in zip(plan.layers, conv_ws):
         lp_tile = getattr(lp, "tile", None)
-        if collect_occupancy:
-            occs.append(occupancy_stat(x, plan.block_c, n_valid, tile=lp_tile))
-        x = run_unit(x, w, lp.to_unit(), lp.kind, lp.impl, plan.block_c,
-                     tile=lp_tile)
-    logits = run_head(x, dense_ws, graph.head())
+        # named scopes put each layer's ops under conv<i> (and the head's
+        # under head) in the compiled program's op metadata, so a profiler
+        # trace attributes device time per layer; they change no op
+        with jax.named_scope(f"conv{lp.index + 1}"):
+            if collect_occupancy:
+                with jax.named_scope("occupancy"):
+                    occs.append(occupancy_stat(x, plan.block_c, n_valid,
+                                               tile=lp_tile))
+            x = run_unit(x, w, lp.to_unit(), lp.kind, lp.impl, plan.block_c,
+                         tile=lp_tile)
+    with jax.named_scope("head"):
+        logits = run_head(x, dense_ws, graph.head())
     if collect_occupancy:
         occs = jnp.stack(occs)
         if axis_name is not None:
-            import jax
-
             if n_valid is None:
                 occs = jax.lax.pmean(occs, axis_name)
             else:
@@ -470,7 +476,6 @@ def run_plan_sharded(plan: PipelinePlan, params, imgs, mesh, *,
     buckets guarantee it, and anything else raises here rather than silently
     replicating.
     """
-    import jax
     from jax.sharding import PartitionSpec as P
 
     if imgs.ndim == 3:

@@ -133,6 +133,11 @@ class MicroBatcher:
         # unsharded legacy clamp: max_batch=1 callers explicitly want singletons
         self.min_bucket = min(self.min_bucket, max(1, self.max_batch // self.align))
 
+    @property
+    def next_id(self) -> int:
+        """The request id the next `submit` returns."""
+        return self._next_id
+
     def submit(self, img, now: float | None = None) -> int:
         """Queue one image; returns its request id (submission order)."""
         rid = self._next_id

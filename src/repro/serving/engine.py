@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graph import as_graph
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import PROFILER_TRACER
 from repro.parallel.api import data_mesh, sharding_for
 from repro.pipeline.planner import PipelinePlan, plan_network, run_plan, run_plan_sharded
 from repro.serving.batcher import MicroBatch, MicroBatcher, SimClock
@@ -130,9 +130,10 @@ class Engine:
                  metrics: MetricsTracker | None = None,
                  sim_service_s=None, tracer=None, calibration=None,
                  tiles=None, int8: bool = False, int8_budget: float = 0.98):
-        # tracer: a repro.obs.trace.Tracer recording plan/compile/execute/
-        # re-plan spans (DESIGN.md §9); the NULL_TRACER default is a shared
-        # no-op object, so the untraced hot path allocates nothing.
+        # tracer: where the engine's serve.* spans go (DESIGN.md §9). The
+        # default writes them into the profiler's own trace, on the device
+        # ops' clock (one TraceMe check a span while no profiler runs); a
+        # repro.obs.trace.Tracer records them on the engine's clock instead.
         # calibration: a repro.obs.calibrate.CalibrationDB — every plan this
         # engine builds (initial, drift re-plans, hot-swap re-plans) prices
         # its impl choices at the measured effective constants; None (or an
@@ -142,7 +143,7 @@ class Engine:
         # layer (plan_network(tiles=...)); often the same DB as calibration.
         # int8/int8_budget: let every plan upgrade layers to the quantized
         # impls under the probe-agreement budget (plan_network(int8=...)).
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.tracer = tracer if tracer is not None else PROFILER_TRACER
         self.calibration = calibration
         self.tiles = tiles
         self.int8 = bool(int8)
@@ -152,7 +153,7 @@ class Engine:
         if plan is None:
             if calib is None:
                 raise ValueError("Engine needs either a prebuilt plan= or calib= images to plan on")
-            with self.tracer.span("plan", graph=graph.name,
+            with self.tracer.span("serve.plan", graph=graph.name,
                                   occ_threshold=occ_threshold):
                 plan = plan_network(params, calib, graph,
                                     occ_threshold=occ_threshold,
@@ -209,10 +210,6 @@ class Engine:
         self.replan_errors = 0
         self.n_hot_swaps = 0
         self.verify_rejects = 0  # plans the static verifier refused to adopt
-        self.n_batches = 0
-        self.n_requests = 0
-        self.n_pad_samples = 0
-        self._fill_sum = 0.0
         self._profile_summary = None  # last Engine.profile() digest
 
     # ------------------------------------------------------------------
@@ -225,9 +222,11 @@ class Engine:
         which can precede the clock when execution of a previous batch
         advanced the simulated timeline past it (the queueing delay behind an
         executing batch must count against latency and the deadline)."""
-        self.n_requests += 1
-        rid = self.batcher.submit(jnp.asarray(img, jnp.float32), now=now)
-        self.metrics.on_submit(self.clock() if now is None else now)
+        with self.tracer.span("serve.submit", rid=self.batcher.next_id):
+            with self.tracer.span("serve.put"):  # the host -> device copy
+                x = jnp.asarray(img, jnp.float32)
+            rid = self.batcher.submit(x, now=now)
+            self.metrics.on_submit()
         return rid
 
     def next_deadline(self) -> float | None:
@@ -294,10 +293,10 @@ class Engine:
         return {
             **self.cache.stats(),
             "devices": self.n_devices,
-            "requests": self.n_requests,
-            "batches": self.n_batches,
-            "pad_samples": self.n_pad_samples,
-            "mean_fill": self._fill_sum / max(self.n_batches, 1),
+            "requests": self.metrics.submitted,
+            "batches": self.metrics.batches,
+            "pad_samples": self.metrics.pad_samples,
+            "mean_fill": self.metrics.mean_fill(),
             "replans": self.n_replans,
             "replan_errors": self.replan_errors,
             "hot_swaps": self.n_hot_swaps,
@@ -351,7 +350,7 @@ class Engine:
         plan, params, mesh = self.plan, self.params, self.mesh
 
         def build():
-            with self.tracer.span("compile", bucket=bucket,
+            with self.tracer.span("serve.compile", bucket=bucket,
                                   devices=self.n_devices):
                 c, h, w = plan.layers[0].in_shape
                 imgs_s = jax.ShapeDtypeStruct((bucket, c, h, w), jnp.float32)
@@ -377,28 +376,45 @@ class Engine:
                             self.mesh)
 
     def _run_batch(self, batch: MicroBatch) -> list:
-        # spans on the engine's own clock: under a SimClock the service time
-        # charged to the timeline is exactly the span duration, so traced
+        # one serve.batch span with a child span per host step. A batch's
+        # requests have consecutive ids from first_rid, so each request's
+        # serve.submit is found from its batch. Under a SimClock only
+        # serve.fetch moves the clock (by the charged service time), so a
+        # Tracer's serve.batch duration is exactly that charge and traced
         # replays are deterministic (tests/test_obs.py pins the bytes)
-        with self.tracer.span("execute_batch", bucket=batch.bucket,
-                              n_real=batch.n_real):
-            return self._run_batch_traced(batch)
+        span = self.tracer.span
+        with span("serve.batch", bucket=batch.bucket, n_real=batch.n_real,
+                  first_rid=batch.requests[0].id):
+            with span("serve.stack"):
+                imgs = jnp.stack([r.img for r in batch.requests])
+                if batch.bucket > batch.n_real:  # ragged tail: all-zero pads
+                    pad = jnp.zeros((batch.bucket - batch.n_real,) + imgs.shape[1:],
+                                    imgs.dtype)
+                    imgs = jnp.concatenate([imgs, pad])
+                if self.mesh is not None:
+                    # commit the batch to the compiled layout (a no-op re-put
+                    # when already placed; uncommitted host arrays would also
+                    # auto-shard, but an explicitly committed input must
+                    # never silently reshard)
+                    imgs = jax.device_put(imgs, self._batch_sharding(imgs.shape))
+            with span("serve.lookup"):
+                exe = self._executable(batch.bucket)
+            t0 = time.perf_counter()
+            with span("serve.dispatch"):
+                logits, occs = exe(self.params, imgs,
+                                   jnp.asarray(batch.n_real, jnp.int32))
+            with span("serve.wait"):
+                jax.block_until_ready(logits)
+            wall = time.perf_counter() - t0
+            with span("serve.fetch"):
+                results = self._finish_batch(batch, imgs, logits, wall)
+            with span("serve.observe"):
+                # after results exist: a re-plan failure must not drop
+                # served work
+                self._observe(np.asarray(occs))
+            return results
 
-    def _run_batch_traced(self, batch: MicroBatch) -> list:
-        imgs = jnp.stack([r.img for r in batch.requests])
-        if batch.bucket > batch.n_real:  # ragged tail: all-zero pad samples
-            pad = jnp.zeros((batch.bucket - batch.n_real,) + imgs.shape[1:], imgs.dtype)
-            imgs = jnp.concatenate([imgs, pad])
-        if self.mesh is not None:
-            # commit the batch to the compiled layout (a no-op re-put when
-            # already placed; uncommitted host arrays would also auto-shard,
-            # but an explicitly committed input must never silently reshard)
-            imgs = jax.device_put(imgs, self._batch_sharding(imgs.shape))
-        exe = self._executable(batch.bucket)
-        t0 = time.perf_counter()
-        logits, occs = exe(self.params, imgs, jnp.asarray(batch.n_real, jnp.int32))
-        jax.block_until_ready(logits)
-        wall = time.perf_counter() - t0
+    def _finish_batch(self, batch: MicroBatch, imgs, logits, wall: float) -> list:
         # the time CHARGED to the timeline: measured wall by default, or the
         # deterministic sim_service_s model (fixed or per-bucket) so seeded
         # SimClock replays are bit-identical end to end
@@ -412,9 +428,6 @@ class Engine:
             self.clock.advance(dt)  # charge service time to the sim timeline
         t_done = self.clock()
         logits = np.asarray(logits)
-        self.n_batches += 1
-        self.n_pad_samples += batch.bucket - batch.n_real
-        self._fill_sum += batch.fill
         self._calib_recent = imgs[: batch.n_real]
         results = [ServedResult(id=r.id, logits=logits[i], t_arrival=r.t_arrival,
                                 t_done=t_done, t_formed=batch.t_formed)
@@ -422,8 +435,7 @@ class Engine:
         self.metrics.on_batch(t_done, batch.bucket, batch.n_real, dt)
         for r in results:
             self.metrics.on_result(r.latency_s)
-        self._observe(np.asarray(occs))  # after results exist: a re-plan
-        return results                   # failure must not drop served work
+        return results
 
     # ------------------------------------------------------------------
     # occupancy drift -> background re-plan
@@ -454,7 +466,7 @@ class Engine:
 
         def work():
             try:
-                with self.tracer.span("replan", trigger="occupancy_drift"):
+                with self.tracer.span("serve.replan", trigger="occupancy_drift"):
                     new = plan_network(self.params, calib, self.graph,
                                        occ_threshold=plan.occ_threshold,
                                        block_c=plan.block_c,
@@ -551,7 +563,7 @@ class Engine:
             if calib is None:
                 raise ValueError("hot_swap needs plan= or calib= before the "
                                  "engine has executed its first batch")
-            with self.tracer.span("plan", graph=self.graph.name,
+            with self.tracer.span("serve.plan", graph=self.graph.name,
                                   trigger="hot_swap"):
                 plan = plan_network(params, calib, self.graph,
                                     occ_threshold=self.plan.occ_threshold,

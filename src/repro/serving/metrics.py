@@ -132,7 +132,7 @@ class MetricsTracker:
 
     # -- engine hooks ------------------------------------------------------
 
-    def on_submit(self, t: float) -> None:
+    def on_submit(self) -> None:
         self.submitted += 1
 
     def on_batch(self, t: float, bucket: int, n_real: int,

@@ -104,8 +104,8 @@ def test_latency_reservoir_bounded_and_seed_deterministic():
 
 def test_metrics_tracker_snapshot_counts_and_json():
     t = MetricsTracker()
-    t.on_submit(0.0)
-    t.on_submit(0.001)
+    t.on_submit()
+    t.on_submit()
     t.on_batch(0.01, bucket=4, n_real=3, service_s=SERVICE_S)
     t.on_result(0.010)
     t.on_result(0.009)

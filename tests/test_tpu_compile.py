@@ -13,6 +13,7 @@ that runs this file loads the TPU compiler), and the tests skip only when it
 cannot be described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,3 +133,57 @@ def test_vmem_model_bounds_the_compiler(one_chip, monkeypatch, launch):
                            sx=sx, sw=sw)
 
     assert "tpu_custom_call" in _compiled_text(run, *specs)
+
+
+def _op_names(text: str) -> set:
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def test_bucket_program_names_layers_and_kernels_for_v5e(one_chip):
+    """The engine's bucket program puts each planned layer's ops under
+    conv<i>, the head's under head, and each Pallas kernel under its name:
+    the op paths a profiler trace reports as `tf_op`."""
+    from repro.configs.vgg19_sparse import CNNConfig, vgg19_graph
+    from repro.core import dead_channel_band
+    from repro.graph import init_graph
+    from repro.models.cnn import shift_dead_channels
+    from repro.pipeline import plan_network
+    from repro.serving.engine import _make_runner
+
+    graph = vgg19_graph(CNNConfig(name="names", in_channels=16, img_size=12,
+                                  plan=((8, 2), (16, 1)), n_classes=4))
+    params = shift_dead_channels(init_graph(jax.random.PRNGKey(0), graph))
+    c, h, w = graph.in_shape
+    calib = dead_channel_band(
+        jax.random.uniform(jax.random.PRNGKey(1), (2, c, h, w)), 0.5)
+    # a threshold above every occupancy puts each layer on a Pallas kernel
+    plan = plan_network(params, calib, graph, occ_threshold=1.01, block_c=8)
+    assert [lp.impl for lp in plan.layers] == ["ecr_pallas", "pecr_pallas",
+                                               "pecr_pallas"]
+    specs = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), params)
+    text = jax.jit(_make_runner(plan)).lower(
+        specs, _spec(one_chip, (4, c, h, w)),
+        _spec(one_chip, (), jnp.int32)).compile().as_text()
+    names = _op_names(text)
+    scopes = {n.split("/")[1] for n in names if n.startswith("jit(run)/")}
+    assert {"conv1", "conv2", "conv3", "head"} <= scopes
+    assert any(n.startswith("jit(run)/conv2/occupancy/") for n in names)
+    kernels = {n for n in names if n.endswith("/pallas_call")}
+    assert kernels == {
+        "jit(run)/conv1/jit(ecr_conv)/cond/branch_0_fun/ecr_conv/pallas_call",
+        "jit(run)/conv2/jit(fused_conv_pool)/cond/branch_0_fun/pecr_conv/pallas_call",
+        "jit(run)/conv3/jit(fused_conv_pool)/cond/branch_0_fun/pecr_conv/pallas_call",
+    }
+    assert any(n.startswith("jit(run)/head/") and n.endswith("dot_general")
+               for n in names)
+
+
+@pytest.mark.parametrize("op,kernel", [
+    (ecr_conv_int8, "ecr_conv_int8"),
+    (conv2d_bsr, "bsr_matmul"),
+    (conv2d_bsr_int8, "bsr_matmul_int8"),
+], ids=["ecr_int8", "bsr", "bsr_int8"])
+def test_kernel_names_for_v5e(one_chip, op, kernel):
+    text = _compiled_text(op, *_conv_specs(one_chip, 16, 10, 16))
+    assert {n.rsplit("/", 2)[-2] for n in _op_names(text)
+            if n.endswith("/pallas_call")} == {kernel}
