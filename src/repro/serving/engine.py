@@ -204,7 +204,7 @@ class Engine:
         self._replan_thread: threading.Thread | None = None
         self._plan_gen = 0  # bumped by hot_swap: stale background re-plans
         self._cooldown = 0  # (planned against the swapped-out params) drop
-        self._calib_recent = None  # last real (unpadded) executed batch
+        self._calib_recent = None  # last real (unpadded) batch, on the host
         self._occ_ema = np.array([lp.occupancy for lp in plan.layers])
         self.n_replans = 0
         self.replan_errors = 0
@@ -217,14 +217,18 @@ class Engine:
     # ------------------------------------------------------------------
 
     def submit(self, img, now: float | None = None) -> int:
-        """Queue one (C,H,W) image; returns the request id. `now` overrides
-        the arrival stamp — replay_stream passes the TRUE scheduled arrival,
-        which can precede the clock when execution of a previous batch
-        advanced the simulated timeline past it (the queueing delay behind an
-        executing batch must count against latency and the deadline)."""
+        """Queue one (C,H,W) image; returns the request id. The engine keeps
+        its own float32 host copy of `img` (numpy, list or jax array alike),
+        so later writes to the caller's buffer never reach the served batch;
+        the image goes to the device with the rest of its batch, in one
+        transfer (`_run_batch`). `now` overrides the arrival stamp —
+        replay_stream passes the TRUE scheduled arrival, which can precede
+        the clock when execution of a previous batch advanced the simulated
+        timeline past it (the queueing delay behind an executing batch must
+        count against latency and the deadline)."""
         with self.tracer.span("serve.submit", rid=self.batcher.next_id):
-            with self.tracer.span("serve.put"):  # the host -> device copy
-                x = jnp.asarray(img, jnp.float32)
+            with self.tracer.span("serve.put"):  # the request's host copy
+                x = np.array(img, dtype=np.float32)
             rid = self.batcher.submit(x, now=now)
             self.metrics.on_submit()
         return rid
@@ -331,11 +335,11 @@ class Engine:
         be profiled without new inputs."""
         from repro.obs.profile import PROFILE_IMPLS, profile_plan
 
-        calib = self._calib_recent if imgs is None else jnp.asarray(imgs)
+        calib = self._calib_recent if imgs is None else imgs
         if calib is None:
             raise ValueError("profile() needs imgs= before the engine has "
                              "executed its first batch")
-        report = profile_plan(self.plan, self.params, calib,
+        report = profile_plan(self.plan, self.params, jnp.asarray(calib),
                               impls=PROFILE_IMPLS if impls is None else impls,
                               iters=iters, warmup=warmup, tracer=self.tracer)
         self._profile_summary = report.summary()
@@ -386,35 +390,35 @@ class Engine:
         with span("serve.batch", bucket=batch.bucket, n_real=batch.n_real,
                   first_rid=batch.requests[0].id):
             with span("serve.stack"):
-                imgs = jnp.stack([r.img for r in batch.requests])
-                if batch.bucket > batch.n_real:  # ragged tail: all-zero pads
-                    pad = jnp.zeros((batch.bucket - batch.n_real,) + imgs.shape[1:],
-                                    imgs.dtype)
-                    imgs = jnp.concatenate([imgs, pad])
-                if self.mesh is not None:
-                    # commit the batch to the compiled layout (a no-op re-put
-                    # when already placed; uncommitted host arrays would also
-                    # auto-shard, but an explicitly committed input must
-                    # never silently reshard)
-                    imgs = jax.device_put(imgs, self._batch_sharding(imgs.shape))
+                # the bucket is assembled on the host (all-zero rows pad a
+                # ragged tail) and reaches the device in one device_put with
+                # n_valid; with a mesh each shard goes straight to its chip
+                host = np.zeros((batch.bucket,) + batch.requests[0].img.shape,
+                                np.float32)
+                np.stack([r.img for r in batch.requests],
+                         out=host[:batch.n_real])
+                placement = None if self.mesh is None else (
+                    self._batch_sharding(host.shape),
+                    sharding_for((), (), self.mesh))
+                imgs, n_valid = jax.device_put(
+                    (host, np.int32(batch.n_real)), placement)
             with span("serve.lookup"):
                 exe = self._executable(batch.bucket)
             t0 = time.perf_counter()
             with span("serve.dispatch"):
-                logits, occs = exe(self.params, imgs,
-                                   jnp.asarray(batch.n_real, jnp.int32))
+                logits, occs = exe(self.params, imgs, n_valid)
             with span("serve.wait"):
                 jax.block_until_ready(logits)
             wall = time.perf_counter() - t0
             with span("serve.fetch"):
-                results = self._finish_batch(batch, imgs, logits, wall)
+                results = self._finish_batch(batch, host, logits, wall)
             with span("serve.observe"):
                 # after results exist: a re-plan failure must not drop
                 # served work
                 self._observe(np.asarray(occs))
             return results
 
-    def _finish_batch(self, batch: MicroBatch, imgs, logits, wall: float) -> list:
+    def _finish_batch(self, batch: MicroBatch, host, logits, wall: float) -> list:
         # the time CHARGED to the timeline: measured wall by default, or the
         # deterministic sim_service_s model (fixed or per-bucket) so seeded
         # SimClock replays are bit-identical end to end
@@ -428,7 +432,7 @@ class Engine:
             self.clock.advance(dt)  # charge service time to the sim timeline
         t_done = self.clock()
         logits = np.asarray(logits)
-        self._calib_recent = imgs[: batch.n_real]
+        self._calib_recent = host[: batch.n_real]  # a host view: no device op
         results = [ServedResult(id=r.id, logits=logits[i], t_arrival=r.t_arrival,
                                 t_done=t_done, t_formed=batch.t_formed)
                    for i, r in enumerate(batch.requests)]
@@ -457,9 +461,9 @@ class Engine:
             self._launch_replan()
 
     def _launch_replan(self) -> None:
-        calib = self._calib_recent
-        if calib is None:
+        if self._calib_recent is None:
             return
+        calib = jnp.asarray(self._calib_recent)
         self._replanning = True
         plan = self.plan
         gen = self._plan_gen
@@ -565,7 +569,7 @@ class Engine:
                                  "engine has executed its first batch")
             with self.tracer.span("serve.plan", graph=self.graph.name,
                                   trigger="hot_swap"):
-                plan = plan_network(params, calib, self.graph,
+                plan = plan_network(params, jnp.asarray(calib), self.graph,
                                     occ_threshold=self.plan.occ_threshold,
                                     block_c=self.plan.block_c,
                                     use_pallas=self.use_pallas,

@@ -199,6 +199,72 @@ def test_engine_poll_drains_burst_of_full_buckets(params):
     assert eng.poll() == []  # nothing left due
 
 
+@pytest.mark.parametrize("n", [4, 3], ids=["full", "ragged"])
+def test_engine_submit_keeps_a_private_host_copy(params, n):
+    """submit queues a float32 host array of its own: overwriting the
+    caller's buffers afterwards changes nothing that is served."""
+    eng = _engine(params)  # max_batch=4
+    imgs = [np.array(_img(5000 + i)) for i in range(n)]
+    originals = [x.copy() for x in imgs]
+    for x in imgs:
+        eng.submit(x)
+    for x, r in zip(imgs, eng.batcher._q):
+        assert isinstance(r.img, np.ndarray) and r.img.dtype == np.float32
+        assert not np.shares_memory(r.img, x)
+        x[:] = 1e6  # the caller reuses its buffer
+    served = {r.id: r.logits for r in eng.drain()}
+    ref = np.asarray(run_plan(eng.plan, params, jnp.stack(originals), TINY))
+    assert np.array_equal(np.stack([served[i] for i in range(n)]), ref)
+
+
+@pytest.mark.parametrize("n", [4, 3], ids=["full", "ragged"])
+def test_engine_batch_reaches_the_device_in_one_transfer(params, monkeypatch, n):
+    """A batch is stacked on the host and handed to the device by exactly
+    one jax.device_put (the padded bucket and n_valid together); nothing is
+    stacked on the device."""
+    eng = _engine(params)  # max_batch=4: n=3 pads one all-zero row
+    eng.warmup()  # no tracing inside the counted batch
+    imgs = [_img(5100 + i) for i in range(n)]
+    for img in imgs:
+        eng.submit(img)
+    puts, real_put = [], jax.device_put
+
+    def counting_put(x, *args, **kw):
+        puts.append(x)
+        return real_put(x, *args, **kw)
+
+    def no_stack(*args, **kw):
+        raise AssertionError("jnp.stack in batch assembly")
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    monkeypatch.setattr(jnp, "stack", no_stack)
+    results = eng.drain()
+    monkeypatch.undo()
+    assert len(results) == n and eng.stats()["batches"] == 1
+    assert len(puts) == 1
+    host, n_valid = puts[0]
+    assert isinstance(host, np.ndarray) and host.shape == (4, 16, 12, 12)
+    assert np.array_equal(host[:n], np.stack(imgs)) and not host[n:].any()
+    assert int(n_valid) == n
+
+
+def test_engine_calib_recent_is_the_host_batch(params):
+    """The last real batch stays on the host (no device slice a batch), and
+    the paths that plan or profile on it still run."""
+    eng = _engine(params, ema_alpha=0.5, replan_band=0.2, replan_cooldown=0)
+    imgs = [_img(5200 + i, dead=0) for i in range(3)]
+    eng.serve(imgs)
+    calib = eng._calib_recent
+    assert isinstance(calib, np.ndarray) and calib.shape == (3, 16, 12, 12)
+    assert np.array_equal(calib, np.stack(imgs))
+    eng.profile(iters=1, warmup=0)
+    assert eng.stats()["telemetry"]["profile"] is not None
+    for wave in range(2):  # dense traffic drifts from the sparse plan
+        eng.serve([_img(5300 + wave * 10 + i, dead=0) for i in range(4)])
+    assert eng.n_replans >= 1
+    assert all(lp.impl == "dense" for lp in eng.plan.layers)
+
+
 def test_engine_serve_empty_request_list(params):
     """serve([]) used to crash in np.stack on the empty result list; it must
     return an empty (0, n_classes) float32 array instead."""
