@@ -11,24 +11,79 @@ object whose `layers` list is a linear CNN:
     {"op": "dense", "out": 4096, "relu": true}
 
 over an input of `in_channels` x `image_size` x `image_size`, with no biases.
+
+A configuration that is not such a chain names instead a model module of
+its own, `"module": "chipbench/models/<name>.py"`, a path under the
+benchmark's root, and needs no `layers` or `weights`. The module provides
+
+    layer_shapes(cfg)        a tuple of `Layer`: every conv, in the order the
+                             program scopes them `conv<i>`, then the dense
+                             layers
+    make_weights(cfg, seed)  the params the program's Engine takes, made on
+                             the device from the seed in one jitted call
+    forward(cfg, params, x, operand_dtype=None)
+                             the plain reference: logits of a batch in
+                             jax.numpy, f32 at the highest matmul precision,
+                             every conv's and dense layer's operands rounded
+                             by `operand(..., operand_dtype, ...)` first
+    layer_graph(cfg)         the configuration as the program's LayerGraph
+
+and `layer_shapes`, `make_weights`, `forward` and everything they reach
+here delegate to it. Only `layer_graph` may import the system under test
+(`repro`); the rest, the reference above all, imports nothing of it and
+takes nothing the program has made.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 from dataclasses import dataclass
+from pathlib import Path
 
 F32_BYTES = 4
+# the benchmark's root, where a configuration's module path starts by default
+ROOT = Path(__file__).resolve().parents[1]
+# where `load_config` keeps the resolved path of a configuration's module
+MODULE_PATH = "module_path"
 
 
-def load_config(path) -> dict:
+def load_config(path, root=ROOT) -> dict:
+    """The configuration file at `path`; a model module it names is resolved
+    against the benchmark root `root` and must lie under it."""
     with open(path) as f:
         cfg = json.load(f)
-    for key in ("name", "in_channels", "image_size", "layers", "weights",
-                "serving"):
+    own = ("module",) if "module" in cfg else ("layers", "weights")
+    for key in ("name", "in_channels", "image_size", "serving") + own:
         if key not in cfg:
             raise ValueError(f"{path}: configuration lacks {key!r}")
+    if "module" in cfg:
+        base = Path(root).resolve()
+        mod = (base / cfg["module"]).resolve()
+        if base not in mod.parents:
+            raise ValueError(f"{path}: module {cfg['module']!r} lies outside "
+                             f"the benchmark root {base}")
+        cfg[MODULE_PATH] = str(mod)
     layer_shapes(cfg)  # validates the layer list
     return cfg
+
+
+@functools.cache
+def _load_module(path: str):
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_model_{Path(path).stem}", path)
+    if spec is None:
+        raise FileNotFoundError(path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def model_module(cfg):
+    """The configuration's own model module, loaded once; None for a
+    configuration given as a `layers` list."""
+    path = cfg.get(MODULE_PATH)
+    return None if path is None else _load_module(path)
 
 
 def in_shape(cfg) -> tuple:
@@ -80,7 +135,11 @@ def _pool_len(n: int, p: int, s: int) -> int:
 
 
 def layer_shapes(cfg) -> tuple:
-    """Shape inference over `cfg["layers"]`: a tuple of `Layer`s."""
+    """Shape inference over `cfg["layers"]`, or the model module's: a tuple
+    of `Layer`s."""
+    mod = model_module(cfg)
+    if mod is not None:
+        return tuple(mod.layer_shapes(cfg))
     c, h, w = in_shape(cfg)
     flat = None
     out = []
@@ -195,9 +254,12 @@ def make_weights(cfg, seed: int):
     after it. Every seed thus serves the same function with its channels in
     another order: the planner's occupancy counts live channels, not their
     places, so every seed gets the same plan and the same work, with the
-    weights laid out differently."""
+    weights laid out differently. A model module makes its own."""
     import jax
 
+    mod = model_module(cfg)
+    if mod is not None:
+        return mod.make_weights(cfg, seed)
     layers = layer_shapes(cfg)
     convs = [lyr for lyr in layers if lyr.op == "conv"]
 
@@ -222,7 +284,7 @@ def make_weights(cfg, seed: int):
 # the plain reference, and its lower-precision control
 # ---------------------------------------------------------------------------
 
-def _operand(a, dtype, axes):
+def operand(a, dtype, axes):
     """`a` as a lower-precision path would feed it to the matrix unit
     (None: unchanged). int8 is symmetric, one scale of absmax / 127 over
     `axes`: per sample for activations, per output channel for weights.
@@ -242,10 +304,13 @@ def forward(cfg, params, x, operand_dtype=None):
     """Logits of a batch (N, C, H, W) in straightforward jax.numpy: f32
     throughout at the highest matmul precision. `operand_dtype` rounds every
     conv's and dense layer's operands to a lower precision first (the
-    control); accumulation stays f32."""
+    control); accumulation stays f32. A model module gives its own."""
     import jax
     import jax.numpy as jnp
 
+    mod = model_module(cfg)
+    if mod is not None:
+        return mod.forward(cfg, params, x, operand_dtype)
     hi = jax.lax.Precision.HIGHEST
     ci = di = 0
     for node in cfg["layers"]:
@@ -255,8 +320,8 @@ def forward(cfg, params, x, operand_dtype=None):
             ci += 1
             s, p = node.get("stride", 1), node.get("pad", 0)
             x = jax.lax.conv_general_dilated(
-                _operand(x, operand_dtype, (1, 2, 3)),
-                _operand(w, operand_dtype, (1, 2, 3)),
+                operand(x, operand_dtype, (1, 2, 3)),
+                operand(w, operand_dtype, (1, 2, 3)),
                 window_strides=(s, s), padding=((p, p), (p, p)),
                 dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=hi)
         elif op == "relu":
@@ -270,8 +335,8 @@ def forward(cfg, params, x, operand_dtype=None):
         else:  # dense
             w = params["dense"][di]
             di += 1
-            x = jnp.dot(_operand(x, operand_dtype, (1,)),
-                        _operand(w, operand_dtype, (0,)), precision=hi)
+            x = jnp.dot(operand(x, operand_dtype, (1,)),
+                        operand(w, operand_dtype, (0,)), precision=hi)
             if node.get("relu"):
                 x = jnp.maximum(x, 0.0)
     return x

@@ -1,11 +1,13 @@
 """One run of one benchmark cell, driven by `BENCHMARK.json` and the files
 it names.
 
-The cell's entry names a configuration (`chipbench/configs/<config>.json`),
-a traffic mix (`chipbench/traffic/<mix>.json`) and, through the metric
-lists, one reader per metric (`chipbench/metrics/<metric>.py`, a module
-with `read(run) -> float | None`). Adding a cell, a configuration, a mix or
-a metric adds files and entries; nothing here names one.
+The cell's entry names a configuration (`chipbench/configs/<config>.json`,
+with a model module of its own where it is not a linear `layers` list:
+`chipbench/cnn.py`), a traffic mix (`chipbench/traffic/<mix>.json`) and,
+through the metric lists, one reader per metric
+(`chipbench/metrics/<metric>.py`, a module with `read(run) -> float |
+None`). Adding a cell, a configuration, a mix or a metric adds files and
+entries; nothing here names one.
 
 A run: check the chips, make the weights and the image pool from the seed,
 build the served path (LayerGraph -> plan_network -> PlanCache -> Engine),
@@ -91,7 +93,7 @@ def find_cell(root: Path, workload: str) -> Cell:
                        f"(have {sorted(cells)})")
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
-    cfg = cnn.load_config(root / configs[w["config"]]["file"])
+    cfg = cnn.load_config(root / configs[w["config"]]["file"], root)
     mix = load_json(root / "chipbench" / "traffic" / f"{w['traffic']}.json")
     return Cell(
         name=workload, chips=int(w["chips"]), cfg=cfg, mix=mix,
@@ -149,10 +151,14 @@ def check_devices(chips: int) -> list:
 
 
 def layer_graph(cfg):
-    """The configuration as the system's LayerGraph."""
-    from repro.graph.ir import ConvSpec, DenseSpec, Flatten, LayerGraph, PoolSpec, ReLU
-
+    """The configuration as the system's LayerGraph (the model module's, where
+    the configuration names one)."""
     from chipbench import cnn
+
+    mod = cnn.model_module(cfg)
+    if mod is not None:
+        return mod.layer_graph(cfg)
+    from repro.graph.ir import ConvSpec, DenseSpec, Flatten, LayerGraph, PoolSpec, ReLU
 
     nodes = []
     for node in cfg["layers"]:
@@ -444,5 +450,8 @@ def profile_stretch(load, devices) -> dict:
         reduced = tr.reduce(tr.load_events(tmp), n_devices=len(devices))
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
+    if reduced["dropped"]:
+        log(f"trace: {reduced['dropped']} of {reduced['devices']} chips' "
+            f"records dropped events inside the stretch (trace.whole_planes)")
     reduced["host_t0"], reduced["host_t1"] = a, b
     return reduced

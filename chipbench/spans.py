@@ -169,8 +169,11 @@ def reduce_spans(events: list, n_devices: int) -> dict:
     read_spans(data)` (or hand-made events of the same form)."""
     w0, w1 = _window(events)
     ops = [e for e in events if "category" in e]
-    planes = sorted({e["plane"] for e in ops},
-                    key=lambda p: int(p.rsplit(":", 1)[1]))[:n_devices]
+    cell = sorted({e["plane"] for e in ops},
+                  key=lambda p: int(p.rsplit(":", 1)[1]))[:n_devices]
+    # a chip whose trace dropped events stands aside (`trace.whole_planes`)
+    planes = tr.whole_planes(events, cell, w0, w1) or cell
+    scale = len(cell) / len(planes) if planes else 1.0
     prog = [e for e in events if e["name"].startswith(PREFIX)]
     lines: dict = {}
     for e in prog:
@@ -194,7 +197,7 @@ def reduce_spans(events: list, n_devices: int) -> dict:
             continue
         a, b = max(e["start_ns"], w0), min(e["start_ns"] + e["dur_ns"], w1)
         if b > a:
-            layer_ns[m.group(1)] = layer_ns.get(m.group(1), 0.0) + (b - a)
+            layer_ns[m.group(1)] = layer_ns.get(m.group(1), 0.0) + (b - a) * scale
         k = KERNEL.search(e["tf_op"])
         if k is not None:
             kernels.setdefault(m.group(1), set()).add(k.group(1))

@@ -19,6 +19,21 @@ Everything else counts, wherever an implementation draws its op
 boundaries: XLA's convolutions, the Mosaic ECR/PECR kernels and, around
 them, the layout copies, pads, gathers, schedule sorts and occupancy
 reductions that serve them.
+
+A collective op is one whose `hlo_category`, or HLO op name, starts with
+the opcode of an exchange between chips (`COLLECTIVES`). On a v5e 2x2 the
+data-parallel bucket program has one: XLA combines the occupancy `psum`s
+of a batch into one op of category "all-reduce", named `%all-reduce`, with
+`tf_op` `jit(run)/shard_map/psum:`, about 8 us a batch on each chip. It
+also counts as conv-unit time by the rule above.
+
+Where a chip's trace buffer overflows, the profiler drops that chip's
+events and marks the stretch with a "Trace Buffers Dropped" event on the
+plane's "XLA TraceMe" line (on a v5e 2x2, chip 0 about 1 s into a 2-s
+stretch, its record then empty to the end). Such a chip's record is left
+out where another chip of the cell has a whole one: busy time is averaged
+over the whole records, and sums over the chips are scaled from them to
+the cell's chips, which run the same program on equal shares of a batch.
 """
 from __future__ import annotations
 
@@ -32,7 +47,11 @@ WINDOW_SPAN = "chipbench.window"
 HOST_SPANS = ("client.send", "engine.submit", "engine.poll", "client.wait")
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+DROPPED = "Trace Buffers Dropped"
 TOP = 10
+# HLO opcodes of the ops that exchange data between chips
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
 
 
 # ---------------------------------------------------------------------------
@@ -103,8 +122,9 @@ def _map_entry(b: bytes) -> tuple:
 
 
 def read_xspace(data: bytes) -> list:
-    """Device ops and the harness's host spans of one XSpace: dicts with
-    plane, name, start_ns, dur_ns, and for device ops category and tf_op."""
+    """Device ops, the harness's host spans and the devices' dropped-buffer
+    markers of one XSpace: dicts with plane, name, start_ns, dur_ns, and for
+    device ops category and tf_op."""
     events = []
     for f, plane in _fields(data):
         if f != 1:
@@ -142,8 +162,6 @@ def read_xspace(data: bytes) -> list:
                     t0_ns = lv
                 elif lf == 4:
                     evs.append(lv)
-            if device and lname != OPS_LINE:
-                continue
             for raw_ev in evs:
                 mid = off = dur = 0
                 for ef, ev in _fields(raw_ev):
@@ -154,11 +172,13 @@ def read_xspace(data: bytes) -> list:
                     elif ef == 3:
                         dur = ev
                 mname, stats = meta.get(mid, ("", {}))
+                if device and lname != OPS_LINE and mname != DROPPED:
+                    continue
                 if not device and mname not in HOST_SPANS + (WINDOW_SPAN,):
                     continue
                 e = {"plane": name, "name": mname.split(" = ")[0],
                      "start_ns": t0_ns + off / 1000.0, "dur_ns": dur / 1000.0}
-                if device:
+                if device and lname == OPS_LINE:
                     e["category"] = str(stats.get("hlo_category", "unknown"))
                     e["tf_op"] = str(stats.get("tf_op", ""))
                 events.append(e)
@@ -188,6 +208,21 @@ def in_conv_unit(ev: dict) -> bool:
     return not ev["tf_op"].rstrip(":").endswith("dot_general")
 
 
+def is_collective(ev: dict) -> bool:
+    """A device op that exchanges data between chips (module docstring)."""
+    op = ev["name"].lstrip("%")
+    return ev["category"].startswith(COLLECTIVES) or op.startswith(COLLECTIVES)
+
+
+def whole_planes(events: list, planes: list, w0: float, w1: float) -> list:
+    """The device planes among `planes` whose record dropped no events
+    inside the window [w0, w1] (module docstring)."""
+    cut = {e["plane"] for e in events
+           if e["name"] == DROPPED and "category" not in e
+           and e["start_ns"] < w1 and e["start_ns"] + e["dur_ns"] > w0}
+    return [p for p in planes if p not in cut]
+
+
 def _union(intervals: list) -> list:
     out = []
     for a, b in sorted(intervals):
@@ -201,9 +236,11 @@ def _union(intervals: list) -> list:
 def reduce(events: list, n_devices: int) -> dict:
     """busy_s: seconds in which some op ran, averaged over the devices;
     window_s: the window's length; conv_s: device seconds in the ops of conv
-    units, summed over the devices; breakdown: the ops that took most time and the
-    longest idle gaps, each gap named by the innermost host span it fell
-    in."""
+    units, summed over the devices; collective_s: seconds in which some
+    collective op ran, summed over the devices; devices: the cell's chips in
+    the trace; dropped: how many of them lost events inside the window
+    (`whole_planes`); breakdown: the ops that took most time and the longest
+    idle gaps, each gap named by the innermost host span it fell in."""
     win = [e for e in events if e["name"] == WINDOW_SPAN]
     if not win:
         raise RuntimeError(f"no {WINDOW_SPAN!r} span in the trace")
@@ -216,27 +253,34 @@ def reduce(events: list, n_devices: int) -> dict:
     ops = [e for e in events if "category" in e]
     planes = sorted({e["plane"] for e in ops},
                     key=lambda p: int(p.rsplit(":", 1)[1]))[:n_devices]
-    busy_ns = 0.0
+    whole = whole_planes(events, planes, w0, w1)
+    used = whole or planes  # where no record is whole, all of them
+    scale = len(planes) / len(used) if used else 1.0
+    busy_ns = collective_ns = 0.0
     gaps = []
-    for plane in planes:
+    for plane in used:
         merged = _union([ab for ab in (clip(e) for e in ops
                                        if e["plane"] == plane)
                          if ab[1] > ab[0]])
         busy_ns += sum(b - a for a, b in merged)
+        collective_ns += sum(b - a for a, b in _union(
+            [ab for ab in (clip(e) for e in ops
+                           if e["plane"] == plane and is_collective(e))
+             if ab[1] > ab[0]]))
         edges = [w0] + [x for ab in merged for x in ab] + [w1]
         gaps += [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
     op_ns: dict = {}
     conv_ns = 0.0
     for e in ops:
-        if e["plane"] not in planes:
+        if e["plane"] not in used:
             continue
         a, b = clip(e)
         if b <= a:
             continue
         key = f"{e['name']} [{e['category']}] {e['tf_op']}".rstrip()
-        op_ns[key] = op_ns.get(key, 0.0) + (b - a)
+        op_ns[key] = op_ns.get(key, 0.0) + (b - a) * scale
         if in_conv_unit(e):
-            conv_ns += b - a
+            conv_ns += (b - a) * scale
     host = [(e["start_ns"], e["start_ns"] + e["dur_ns"], e["name"])
             for e in events if "category" not in e and e["name"] in HOST_SPANS]
 
@@ -250,10 +294,12 @@ def reduce(events: list, n_devices: int) -> dict:
     top_ops = sorted(op_ns.items(), key=lambda kv: -kv[1])[:TOP]
     top_gaps = sorted(gaps, key=lambda ab: ab[0] - ab[1])[:TOP]
     return {
-        "busy_s": busy_ns / len(planes) * 1e-9 if planes else 0.0,
+        "busy_s": busy_ns / len(used) * 1e-9 if used else 0.0,
         "window_s": (w1 - w0) * 1e-9,
         "conv_s": conv_ns * 1e-9,
+        "collective_s": collective_ns * scale * 1e-9,
         "devices": len(planes),
+        "dropped": len(planes) - len(whole),
         "breakdown": {
             "device_ops": [[k, v * 1e-9] for k, v in top_ops],
             "idle_gaps": [[host_span(a, b), (b - a) * 1e-9] for a, b in top_gaps],

@@ -100,11 +100,11 @@ def test_int8_operands_take_127_levels_a_channel():
     a = jnp.asarray(np.random.default_rng(0).normal(size=(4, 3, 5, 5)),
                     jnp.float32) * jnp.asarray([1.0, 10.0, 100.0, 0.01])[:, None,
                                                                       None, None]
-    q = np.asarray(cnn._operand(a, jnp.int8, (1, 2, 3)))
+    q = np.asarray(cnn.operand(a, jnp.int8, (1, 2, 3)))
     for o in range(4):
         step = np.abs(np.asarray(a[o])).max() / 127.0
         levels = q[o] / step
         np.testing.assert_allclose(levels, np.round(levels), atol=1e-3)
         assert np.abs(levels).max() == pytest.approx(127.0)
         assert np.abs(q[o] - np.asarray(a[o])).max() <= step / 2 * (1 + 1e-5)
-    assert cnn._operand(a, None, (1, 2, 3)) is a
+    assert cnn.operand(a, None, (1, 2, 3)) is a

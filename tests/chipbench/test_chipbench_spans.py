@@ -287,3 +287,22 @@ def test_recorded_spans_cover_the_conv_time(recorded_spans):
         **{f"conv{i}": ["ecr_conv"] for i in (7, 9, 10, 11, 13, 14, 15)},
         **{f"conv{i}": ["pecr_conv"] for i in (12, 16)}}
     assert any(g.startswith(sp.PREFIX) for g, _ in r["idle_gaps"])
+
+
+def test_a_chip_whose_trace_dropped_events_stands_aside_here_too():
+    """Idle by span and layer time leave out chip 0, whose record ends in a
+    dropped-buffer marker, as `trace.reduce` does."""
+    scope = "jit(run)/shard_map/conv9/jit(ecr_conv)/cond/branch_0_fun/ecr_conv/pallas_call:"
+    events = [span(tr.WINDOW_SPAN, 0, 1000),
+              op(D0, 0, 200, scope, "custom-call"),
+              {"plane": D0, "name": tr.DROPPED, "start_ns": 200, "dur_ns": 800},
+              op(D1, 0, 600, scope, "custom-call"),
+              span("serve.wait", 0, 700), span("serve.fetch", 700, 300)]
+    base = tr.reduce(events, 2)
+    r = sp.reduce_spans(events, 2)
+    assert r["idle_by_span"] == pytest.approx({"serve.wait": 100e-9,
+                                               "serve.fetch": 300e-9})
+    assert sum(r["idle_by_span"].values()) == pytest.approx(
+        base["window_s"] - base["busy_s"], rel=1e-12)
+    assert r["layer_s"] == pytest.approx({"conv9": 2 * 600e-9})
+    assert r["layer_kernels"] == {"conv9": ["ecr_conv"]}

@@ -151,6 +151,20 @@ def test_read_xspace_decodes_ops_and_host_spans():
     assert r["conv_s"] == pytest.approx(2e-9)
 
 
+def test_read_xspace_keeps_the_dropped_buffer_markers():
+    dev = _plane("/device:TPU:0",
+                 {1: ("%fusion.1", [(7, "loop fusion"), (8, "jit(run)/add:")]),
+                  2: (tr.DROPPED, []), 3: ("barrier-cores", [])},
+                 {7: "hlo_category", 8: "tf_op"},
+                 [("XLA Ops", 1000, [(1, 0, 2000)]),
+                  ("XLA TraceMe", 1000, [(3, 0, 500), (2, 4000, 9000)])])
+    events = tr.read_xspace(_field(1, dev))
+    assert [e for e in events if "category" not in e] == [
+        {"plane": "/device:TPU:0", "name": tr.DROPPED, "start_ns": 1004.0,
+         "dur_ns": 9.0}]
+    assert [e["name"] for e in events if "category" in e] == ["%fusion.1"]
+
+
 # -- a trace recorded on the chip ----------------------------------------------
 
 
@@ -214,3 +228,65 @@ def test_xspace_fixed64_and_refs():
                                + _field(3, 1000))))
     (ev,) = tr.read_xspace(_field(1, plane))
     assert ev["category"] == "loop fusion" and ev["plane"] == "/device:TPU:3"
+
+
+def test_collective_time_is_the_union_of_collective_ops_per_chip():
+    """`collective_s`: per chip, the seconds in which some collective op ran
+    inside the window, summed over the cell's chips; no other key moves."""
+    events = [span(tr.WINDOW_SPAN, 0, 1000)]
+    for i in range(4):
+        d = f"/device:TPU:{i}"
+        events += [
+            op(d, 0, 600, "custom-call", "jit(run)/conv3/ecr_conv/pallas_call:"),
+            op(d, 600, 50, "all-reduce", "jit(run)/psum:", "%all-reduce.1"),
+            op(d, 640, 30, "all-reduce", "jit(run)/psum:", "%all-reduce.2"),
+            op(d, 700, 200, "convolution fusion", "jit(run)/head/dot_general:"),
+        ]
+    events.append(op("/device:TPU:0", 990, 40, "all-reduce",
+                     "jit(run)/psum:", "%all-reduce.3"))  # half inside
+    r = tr.reduce(events, n_devices=4)
+    assert r["collective_s"] == pytest.approx((4 * 70 + 10) * 1e-9)
+    assert r["busy_s"] == pytest.approx((4 * 870 + 10) / 4 * 1e-9)
+    assert r["conv_s"] == pytest.approx((4 * (600 + 50 + 30) + 10) * 1e-9)
+    assert tr.reduce(events[:5], 1)["collective_s"] == pytest.approx(70e-9)
+    without = [e for e in events if "category" not in e
+               or not tr.is_collective(e)]
+    assert tr.reduce(without, 4)["collective_s"] == 0
+
+
+@pytest.mark.parametrize("category,name,collective", [
+    ("all-reduce", "%all-reduce.7", True),
+    ("loop fusion", "%all-reduce-start.2", True),
+    ("async-done", "%all-gather-done", True),
+    ("collective-permute", "%fusion.3", True),
+    ("loop fusion", "%fusion.12", False),
+    ("copy-start", "%copy-start.1", False),
+])
+def test_collective_ops_are_named_by_their_hlo_opcode(category, name,
+                                                      collective):
+    ev = op("/device:TPU:0", 0, 1, category, "jit(run)/x:", name)
+    assert tr.is_collective(ev) is collective
+
+
+def test_a_chip_whose_trace_dropped_events_stands_aside():
+    """Chip 0's record ends in a dropped-buffer marker: busy time comes from
+    the whole records, and sums over the chips are scaled from them."""
+    events = [span(tr.WINDOW_SPAN, 0, 1000),
+              op("/device:TPU:0", 0, 300),
+              {"plane": "/device:TPU:0", "name": tr.DROPPED, "start_ns": 300,
+               "dur_ns": 700},
+              op("/device:TPU:1", 0, 600),
+              op("/device:TPU:1", 600, 10, "all-reduce", "jit(run)/psum:",
+                 "%all-reduce")]
+    r = tr.reduce(events, 2)
+    assert r["dropped"] == 1 and r["devices"] == 2
+    assert r["busy_s"] == pytest.approx(610e-9)
+    assert r["conv_s"] == pytest.approx(2 * 610e-9)
+    assert r["collective_s"] == pytest.approx(2 * 10e-9)
+    assert all(g[1] == pytest.approx(390e-9) for g in r["breakdown"]["idle_gaps"])
+    # with no whole record left, the cut one is all there is
+    alone = tr.reduce(events[:3], 1)
+    assert alone["dropped"] == 1 and alone["busy_s"] == pytest.approx(300e-9)
+    # a marker outside the window cuts nothing
+    late = dict(events[2], start_ns=2000)
+    assert tr.reduce(events[:2] + [late] + events[3:], 2)["dropped"] == 0
