@@ -4,13 +4,15 @@ Claim checked: ECR wins on single extracted layers from LeNet / AlexNet /
 GoogLeNet at their published input sparsities (0.90-0.95) — i.e. the
 technique is not VGG-specific.
 
-Since the LayerGraph refactor the LeNet and AlexNet rows are EXTRACTED FROM
-THE REAL NETWORK GRAPHS (`repro.configs.lenet` / `.alexnet`): each row is a
-`ConvUnit` pulled out of the graph, carrying its true input shape, kernel
-size, stride and padding — the 5x5 LeNet conv and AlexNet's 3x3 mid-stack
-run exactly as the full network runs them. GoogLeNet's inception layers
-branch (outside the linear IR), so those rows keep the published synthetic
-shapes from `_util.TABLE3_LAYERS`.
+The rows are EXTRACTED FROM THE REAL NETWORK GRAPHS (`repro.configs.lenet`
+/ `.alexnet` / `.googlenet`): each row is a `ConvUnit` pulled out of the
+graph, carrying its true input shape, kernel size, stride and padding — the
+5x5 LeNet conv, AlexNet's 3x3 mid-stack and GoogLeNet's inception 3x3s run
+exactly as the full network runs them. A GoogLeNet row of
+`_util.TABLE3_LAYERS` whose (input channels, map, output channels, k) match
+no conv of Table 1 (Incep4a.1, Incep5a.1 and Incep4a.7 name 3x3 convs where
+Table 1 has a 1x1 or another map) keeps the row's own shape, unpadded, and
+says so in its `derived` column.
 
 Each layer's input carries the published sparsity twice over: element-level
 (the paper's metric — MAC reduction from zero skipping) and as a dead-channel
@@ -102,16 +104,23 @@ def rows():
                                unit.conv.c_out)
             row["derived"] = f"sparsity={sp} in={unit.in_shape} " + row["derived"]
             out.append(row)
-    # GoogLeNet: inception branches are outside the linear IR — published
-    # synthetic shapes, same harness
+    # GoogLeNet: the inception conv whose shape the row names, else the row
+    from repro.configs.googlenet import GOOGLENET
+    from repro.graph.ir import ConvSpec
+
     for net, layer, size, sp, c, o, k in TABLE3_LAYERS:
         if not net.startswith("GoogLeNet"):
             continue
-        from repro.graph.ir import ConvSpec
-
+        unit = next((u for u in GOOGLENET.units()
+                     if u.in_shape == (c, size, size) and u.conv.c_out == o
+                     and u.conv.k == k), None)
+        where = (f"conv{unit.index + 1} of the graph" if unit is not None
+                 else "the row's shape (it disagrees with Table 1)")
+        conv = unit.conv if unit is not None else ConvSpec(o, k=k, pad=0)
         x = _layer_input(_seed(f"{net}.{layer}"), (c, size, size), sp)
-        row = _bench_layer(f"table3/{net}.{layer}", x, ConvSpec(o, k=k, pad=0), o)
-        row["derived"] = f"sparsity={sp} in=({c}, {size}, {size}) " + row["derived"]
+        row = _bench_layer(f"table3/{net}.{layer}", x, conv, o)
+        row["derived"] = (f"sparsity={sp} in=({c}, {size}, {size}) "
+                          f"from={where} " + row["derived"])
         out.append(row)
     return out
 

@@ -150,9 +150,11 @@ def check_plan(plan, params=None, graph=None, batch: int = 1) -> list:
                 L = None
             check_launch(L, sink, **loc)
 
-    # --- in-shape chain (each layer consumes its predecessor) -------------
-    for prev, nxt in zip(plan.layers, plan.layers[1:]):
-        if tuple(prev.out_shape) != tuple(nxt.in_shape):
+    # --- in-shape chain (each layer consumes its own producer) -------------
+    by_index = {lp.index: lp for lp in plan.layers}
+    for nxt in plan.layers:
+        prev = by_index.get(nxt.reads)
+        if prev is not None and tuple(prev.out_shape) != tuple(nxt.in_shape):
             sink.add("RPA201",
                      f"plan/graph mismatch: conv_{nxt.index + 1} expects "
                      f"input {tuple(nxt.in_shape)} but conv_{prev.index + 1} "
@@ -180,8 +182,8 @@ def check_plan(plan, params=None, graph=None, batch: int = 1) -> list:
                     continue
                 drift = [f"{f}: plan {getattr(u, f)!r} vs graph "
                          f"{getattr(gu, f)!r}"
-                         for f in ("conv", "relu", "pool", "in_shape",
-                                   "out_shape")
+                         for f in ("index", "conv", "relu", "pool",
+                                   "in_shape", "out_shape", "reads")
                          if getattr(u, f) != getattr(gu, f)]
                 if drift:
                     sink.add("RPA201",

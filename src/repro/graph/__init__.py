@@ -2,28 +2,32 @@
 
 `repro.graph` holds the three pieces every stage of the pipeline shares:
 
-- `ir`: the typed network description (`ConvSpec`/`ReLU`/`PoolSpec`/`Flatten`/
-  `DenseSpec` nodes in a `LayerGraph`), static shape inference, and the
-  weight-layout plumbing (`graph_weights`, `init_graph`).
+- `ir`: the typed network description (`ConvSpec`/`ReLU`/`PoolSpec`/`LRN`/
+  `Branches`/`Flatten`/`DenseSpec` nodes in a `LayerGraph`), static shape
+  inference, and the weight-layout plumbing (`graph_weights`, `init_graph`).
 - `registry`: the ONE impl-dispatch site — (kind, impl) -> forward + cost hook
   + fusion metadata — and the PECR fusion rule (`fusion_eligible`).
-- `executor`: graph walking (`run_units`/`run_head`/`run_graph`) plus the
-  structural primitives (`pad2d`, mode-aware `maxpool2d`).
+- `executor`: the one structural walk (`walk_graph`), `run_unit`/`run_head`/
+  `run_graph`, plus the structural primitives (`pad2d`, mode-aware
+  `maxpool2d`, `lrn`).
 
 Network builders live with their configs (`repro.configs.vgg19_sparse.
-vgg19_graph`, `repro.configs.lenet`, `repro.configs.alexnet`); `as_graph`
+vgg19_graph`, `repro.configs.lenet`, `repro.configs.alexnet`,
+`repro.configs.googlenet`); `as_graph`
 bridges the legacy `CNNConfig`-shaped call sites onto the IR.
 """
 from repro.graph.executor import (
+    lrn,
     maxpool2d,
     pad2d,
     run_graph,
     run_head,
     run_unit,
-    run_units,
-    uniform_impls,
+    walk_graph,
 )
 from repro.graph.ir import (
+    LRN,
+    Branches,
     ConvSpec,
     ConvUnit,
     DenseSpec,
@@ -64,6 +68,8 @@ def as_graph(graph_or_cfg) -> LayerGraph:
 
 
 __all__ = [
+    "LRN",
+    "Branches",
     "ConvSpec",
     "ConvUnit",
     "DenseSpec",
@@ -80,14 +86,14 @@ __all__ = [
     "graph_weights",
     "init_graph",
     "list_ops",
+    "lrn",
     "maxpool2d",
     "pad2d",
     "register_op",
     "run_graph",
     "run_head",
     "run_unit",
-    "run_units",
-    "uniform_impls",
     "unit_impl",
+    "walk_graph",
     "weight_shapes",
 ]

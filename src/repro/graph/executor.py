@@ -1,12 +1,17 @@
-"""LayerGraph executor: walk the units, dispatch every op through the registry.
+"""LayerGraph executor: walk the graph, dispatch every op through the registry.
 
-This is the ONE place forward execution happens — `models/cnn.cnn_forward`
-(uniform impl), `pipeline/planner.run_plan` (per-layer planned impls) and the
-serving engine's compiled runners are all thin wrappers over `run_units` +
-`run_head` with different per-unit (kind, impl) assignments. Structural
-concerns (padding, unfused ReLU/pool around a plain conv, flatten, the dense
-head) live here; impl selection lives in `repro.graph.registry`; numerical
-kernels live in core/ and kernels/.
+This is the ONE place forward execution happens. `walk_graph` is the one
+structural walk over a graph's body: it hands each conv unit the tensor it
+actually reads, runs the stand-alone pools and LRNs and joins branches by a
+channel concat, each under its named scope, and calls back per unit. Every
+caller that runs a graph — `run_graph` (uniform impl), the planner's
+calibration walk and `run_plan` (per-layer planned impls, the serving
+engine's compiled runners), the profiler, the tile search and
+`models/cnn.cnn_feature_maps` — is that walk with its own per-unit callback,
+then `run_head`. Structural concerns (padding, unfused ReLU/pool around a
+plain conv, concat, LRN, flatten, the dense head) live here; impl selection
+lives in `repro.graph.registry`; numerical kernels live in core/ and
+kernels/.
 
 The executor is deliberately mesh-OBLIVIOUS: every op is per-sample along
 the batch dim, so under the sharded serving path (DESIGN.md §6) this exact
@@ -20,7 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.graph.ir import ConvUnit, LayerGraph, PoolSpec, graph_weights
+from repro.graph.ir import LRN, ConvUnit, Join, LayerGraph, PoolSpec, graph_weights
 from repro.graph.registry import get_op, unit_impl
 
 # ---------------------------------------------------------------------------
@@ -36,27 +41,49 @@ def pad2d(x, pad: int):
 
 
 def maxpool2d(x, pool: PoolSpec):
-    """Max-pool the trailing two dims per `pool` (p, stride, mode).
+    """Pool the trailing two dims per `pool` (p, stride, mode, pad, kind).
 
     mode="valid" raises on an inexact tiling (the explicit-truncation guard —
     shapes are static, so this is a plain python check even under jit);
     "floor" drops the tail; "ceil" pads with -inf to keep a partial window.
+    A max-pool's `pad` is -inf on each edge; kind="avg" averages each whole
+    window.
     """
     from repro.graph.ir import pool_out_len
 
     h, w = x.shape[-2:]
     oh, ow = pool_out_len(h, pool), pool_out_len(w, pool)  # validates mode
-    pad_h = (oh - 1) * pool.s + pool.p - h if pool.mode == "ceil" else 0
-    pad_w = (ow - 1) * pool.s + pool.p - w if pool.mode == "ceil" else 0
     lead = x.ndim - 2
+    window = dict(window_dimensions=(1,) * lead + (pool.p, pool.p),
+                  window_strides=(1,) * lead + (pool.s, pool.s))
+    if pool.kind == "avg":
+        total = jax.lax.reduce_window(x, 0.0, jax.lax.add, padding="VALID",
+                                      **window)
+        return total / (pool.p * pool.p)
+    tail_h = (oh - 1) * pool.s + pool.p - h - 2 * pool.pad
+    tail_w = (ow - 1) * pool.s + pool.p - w - 2 * pool.pad
     return jax.lax.reduce_window(
         x,
         -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min,
         jax.lax.max,
-        window_dimensions=(1,) * lead + (pool.p, pool.p),
-        window_strides=(1,) * lead + (pool.s, pool.s),
-        padding=((0, 0),) * lead + ((0, max(pad_h, 0)), (0, max(pad_w, 0))),
+        padding=((0, 0),) * lead + ((pool.pad, pool.pad + max(tail_h, 0)),
+                                    (pool.pad, pool.pad + max(tail_w, 0))),
+        **window,
     )
+
+
+def lrn(x, spec: LRN):
+    """Cross-channel local response normalization of (C,H,W) / (N,C,H,W).
+    The window sum is `size` shifted slices of the zero-padded squares
+    along the channel axis: a channel `reduce_window` here is slow on the
+    TPU, and its fusion with the division fails to compile there at
+    batches 1-4 ("Binary op with incompatible shapes")."""
+    half = spec.size // 2
+    c = x.shape[-3]
+    sq = jnp.pad(x * x, ((0, 0),) * (x.ndim - 3)
+                 + ((half, spec.size - 1 - half), (0, 0), (0, 0)))
+    total = sum(sq[..., i:i + c, :, :] for i in range(spec.size))
+    return x / (spec.k + spec.alpha / spec.size * total) ** spec.beta
 
 
 # ---------------------------------------------------------------------------
@@ -84,13 +111,29 @@ def run_unit(x, w, unit: ConvUnit, kind: str, impl: str, block_c: int = 0,
     return x
 
 
-def run_units(x, conv_ws, units, impls, block_c: int = 0, tiles=None):
-    """Run the conv body: `impls` is one (kind, impl) pair per unit; `tiles`
-    (optional) one TileConfig-or-None per unit."""
-    for i, (unit, (kind, impl), w) in enumerate(zip(units, impls, conv_ws)):
-        tile = tiles[i] if tiles is not None else None
-        x = run_unit(x, w, unit, kind, impl, block_c, tile=tile)
-    return x
+def walk_graph(graph: LayerGraph, x, on_unit):
+    """Run the graph's body on x ((C,H,W) or (N,C,H,W)) and return what
+    enters the head. `on_unit(unit, x) -> y` runs each `ConvUnit` on the
+    tensor it reads, in program order; a stand-alone step runs under its
+    scope (`pool<j>`, `avgpool`, `lrn<j>`), a join's paths under its name and
+    their channel concat under `concat` inside it."""
+
+    def run(steps, x):
+        for st in steps:
+            if isinstance(st, ConvUnit):
+                x = on_unit(st, x)
+            elif isinstance(st, Join):
+                with jax.named_scope(st.name):
+                    outs = [run(path, x) for path in st.paths]
+                    with jax.named_scope("concat"):
+                        x = jnp.concatenate(outs, axis=-3)
+            else:
+                with jax.named_scope(st.scope):
+                    x = lrn(x, st.node) if isinstance(st.node, LRN) \
+                        else maxpool2d(x, st.node)
+        return x
+
+    return run(graph.body(), x)
 
 
 def run_head(x, dense_ws, head):
@@ -103,17 +146,14 @@ def run_head(x, dense_ws, head):
     return x
 
 
-def uniform_impls(graph: LayerGraph, impl: str) -> tuple:
-    """One whole-network impl string -> per-unit (kind, impl) assignments
-    (fused-family impls land on fusion-eligible units, their conv fallback
-    elsewhere — the registry's `unit_impl` rule)."""
-    return tuple(unit_impl(u, impl) for u in graph.units())
-
-
 def run_graph(graph: LayerGraph, params, x, impl: str = "dense",
               block_c: int = 0):
     """(C,H,W) or (N,C,H,W) -> logits through the whole graph at one uniform
     impl. Per-layer planned execution is `repro.pipeline.run_plan`."""
     conv_ws, dense_ws = graph_weights(params)
-    x = run_units(x, conv_ws, graph.units(), uniform_impls(graph, impl), block_c)
-    return run_head(x, dense_ws, graph.head())
+
+    def on_unit(unit, x):
+        kind, op = unit_impl(unit, impl)
+        return run_unit(x, conv_ws[unit.index], unit, kind, op, block_c)
+
+    return run_head(walk_graph(graph, x, on_unit), dense_ws, graph.head())

@@ -117,13 +117,15 @@ def list_ops(kind: str | None = None) -> tuple:
 
 def fusion_eligible(unit: ConvUnit) -> bool:
     """conv+ReLU+pool -> PECR is legal iff the triple is adjacent AND the
-    pool is the kernel-supported form: stride == p (non-overlapping) and the
-    conv output tiles exactly (the fused epilogue floors; an inexact tiling
-    would silently truncate, exactly what PoolSpec mode='valid' guards)."""
+    pool is the kernel-supported form: an unpadded max-pool with stride == p
+    (non-overlapping) and the conv output tiled exactly (the fused epilogue
+    floors; an inexact tiling would silently truncate, exactly what PoolSpec
+    mode='valid' guards)."""
     pool = unit.pool
     if pool is None or not unit.relu:
         return False
-    if pool.s != pool.p or pool.mode == "ceil":
+    if pool.s != pool.p or pool.mode == "ceil" or pool.pad \
+            or pool.kind != "max":
         return False
     _, oh, ow = unit.conv_out_shape
     return oh % pool.p == 0 and ow % pool.p == 0

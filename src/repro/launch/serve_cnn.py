@@ -12,6 +12,7 @@ Mosaic; `python chip_smoke.py` runs this path and checks its logits):
 Other networks:
     PYTHONPATH=src python -m repro.launch.serve_cnn --model lenet
     PYTHONPATH=src python -m repro.launch.serve_cnn --model alexnet
+    PYTHONPATH=src python -m repro.launch.serve_cnn --model googlenet
 Autotuned plan:
     PYTHONPATH=src python -m repro.launch.serve_cnn --autotune
 Data-parallel over 4 virtual CPU devices (DESIGN.md §6):
@@ -55,7 +56,7 @@ from repro.serving import Engine, SimClock, auto_mesh, autotune, replay_stream
 
 log = logging.getLogger("repro.serve_cnn")
 
-MODELS = ("vgg19", "lenet", "alexnet")
+MODELS = ("vgg19", "lenet", "alexnet", "googlenet")
 SCENARIOS = ("steady", "burst", "diurnal", "hotswap", "multitenant")
 
 
@@ -73,6 +74,10 @@ def serving_graph(model: str = "vgg19", full: bool = False) -> LayerGraph:
         from repro.configs.alexnet import ALEXNET, ALEXNET_REDUCED
 
         return ALEXNET if full else ALEXNET_REDUCED
+    if model == "googlenet":
+        from repro.configs.googlenet import GOOGLENET, GOOGLENET_REDUCED
+
+        return GOOGLENET if full else GOOGLENET_REDUCED
     if model != "vgg19":
         raise ValueError(f"unknown --model {model!r} (choose from {MODELS})")
     if full:

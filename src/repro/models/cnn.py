@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.vgg19_sparse import CNNConfig, vgg19_graph
-from repro.graph.executor import maxpool2d, pad2d, run_head, run_units, uniform_impls
+from repro.graph.executor import maxpool2d, pad2d, run_graph, run_unit, walk_graph
 from repro.graph.ir import PoolSpec, graph_weights
 
 
@@ -67,28 +67,18 @@ def _maxpool(x, p, stride: int = 0, mode: str = "valid"):
     return maxpool2d(x, PoolSpec(p, stride=stride, mode=mode))
 
 
-def _features(params, img, *, impl: str, ccfg: CNNConfig):
-    """(C,H,W) -> (C_out, h, w) after all conv stages; batched (N,C,H,W) ->
-    (N, C_out, h, w). Every conv/conv_pool call carries the whole batch, so
-    each layer is ONE jitted op (batched Pallas grid for the *_pallas impls,
-    native lax / vmapped oracle batching otherwise). Impl resolution — which
-    units fuse, which conv family backs a fused request — is the registry's
-    `unit_impl` rule, not local string matching."""
-    graph = vgg19_graph(ccfg)
-    conv_ws, _ = graph_weights(params)
-    return run_units(img, conv_ws, graph.units(), uniform_impls(graph, impl))
-
-
 def cnn_forward(params, img, impl: str = "dense", ccfg: CNNConfig = CNNConfig()):
     """(C,H,W) -> class logits, or a batch (N,C,H,W) -> (N, n_classes).
 
     The batch flows through the conv stack as whole-batch layer calls (not a
     python loop over samples); see `cnn_forward_batch` for the explicit API.
+    Every conv/conv_pool call carries the whole batch, so each layer is ONE
+    jitted op (batched Pallas grid for the *_pallas impls, native lax /
+    vmapped oracle batching otherwise). Impl resolution — which units fuse,
+    which conv family backs a fused request — is the registry's `unit_impl`
+    rule, not local string matching.
     """
-    graph = vgg19_graph(ccfg)
-    x = _features(params, img, impl=impl, ccfg=ccfg)
-    _, dense_ws = graph_weights(params)
-    return run_head(x, dense_ws, graph.head())
+    return run_graph(vgg19_graph(ccfg), params, img, impl)
 
 
 def cnn_forward_batch(params, imgs, impl: str = "dense", ccfg: CNNConfig = CNNConfig()):
@@ -131,15 +121,14 @@ def shift_dead_channels(params, rate: float = 0.04, shift: float = 0.12):
 
 def cnn_feature_maps(params, img, ccfg: CNNConfig = CNNConfig()):
     """The paper's data set (§VI-A): every feature map ENTERING a conv layer."""
-    from repro.graph.executor import run_unit
-
-    graph = vgg19_graph(ccfg)
     conv_ws, _ = graph_weights(params)
     maps = []
-    x = img
-    for unit, w in zip(graph.units(), conv_ws):
+
+    def on_unit(unit, x):
         maps.append(x)
-        x = run_unit(x, w, unit, "conv", "dense")
+        return run_unit(x, conv_ws[unit.index], unit, "conv", "dense")
+
+    walk_graph(vgg19_graph(ccfg), img, on_unit)
     return maps
 
 

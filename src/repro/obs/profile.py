@@ -238,7 +238,7 @@ def profile_plan(plan, params, calib, *, impls=PROFILE_IMPLS, iters: int = 3,
     """
     import jax
 
-    from repro.graph.executor import run_unit
+    from repro.graph.executor import run_unit, walk_graph
     from repro.graph.ir import graph_weights
     from repro.graph.registry import unit_cost, unit_impl, unit_model_us
     from repro.obs.trace import NULL_TRACER
@@ -255,44 +255,46 @@ def profile_plan(plan, params, calib, *, impls=PROFILE_IMPLS, iters: int = 3,
     batch = int(calib.shape[0])
     conv_ws, _ = graph_weights(params)
     timings: list = []
-    units = tuple(graph.units())
-    x = calib
+
+    def on_unit(unit, x):
+        w = conv_ws[unit.index]
+        occ = measure_occupancy(x, plan.block_c)
+        wd = weight_block_density(w)
+        seen: set = set()
+        for family in impls:
+            kind, impl = unit_impl(unit, family)
+            if (kind, impl) in seen:
+                continue
+            seen.add((kind, impl))
+
+            def fwd(x_, w_, unit=unit, kind=kind, impl=impl):
+                return run_unit(x_, w_, unit, kind, impl, plan.block_c)
+
+            with tracer.span("profile_layer", cat="kernel",
+                             layer=unit.index, kind=kind, impl=impl):
+                t = time_callable(jax.jit(fwd), x, w, iters=iters,
+                                  warmup=warmup, outlier_tol=outlier_tol)
+            conv = unit.conv
+            c, h, wdt = unit.in_shape
+            cost = unit_cost(
+                kind, impl, c=c, h=h + 2 * conv.pad, w=wdt + 2 * conv.pad,
+                o=conv.c_out, k=conv.k, stride=conv.stride,
+                pool=unit.pool.p if unit.pool is not None else None,
+                occupancy=occ, weight_density=wd, batch=batch)
+            timings.append(LayerTiming(
+                index=unit.index, kind=kind, impl=impl, occupancy=occ,
+                weight_density=wd, batch=batch, block_c=plan.block_c,
+                measured_us=t.median_us, spread=t.spread,
+                predicted_us=unit_model_us(
+                    kind, impl, unit, occupancy=occ, weight_density=wd,
+                    batch=batch, block_c=plan.block_c),
+                flops=float(cost["flops"]), bytes=float(cost["bytes"])))
+        return run_unit(x, w, unit, "conv", "dense")  # what the next units read
+
     with tracer.span("profile", graph=graph.name, batch=batch):
-        for unit, w in zip(units, conv_ws):
-            occ = measure_occupancy(x, plan.block_c)
-            wd = weight_block_density(w)
-            seen: set = set()
-            for family in impls:
-                kind, impl = unit_impl(unit, family)
-                if (kind, impl) in seen:
-                    continue
-                seen.add((kind, impl))
-
-                def fwd(x_, w_, unit=unit, kind=kind, impl=impl):
-                    return run_unit(x_, w_, unit, kind, impl, plan.block_c)
-
-                with tracer.span("profile_layer", cat="kernel",
-                                 layer=unit.index, kind=kind, impl=impl):
-                    t = time_callable(jax.jit(fwd), x, w, iters=iters,
-                                      warmup=warmup, outlier_tol=outlier_tol)
-                conv = unit.conv
-                c, h, wdt = unit.in_shape
-                cost = unit_cost(
-                    kind, impl, c=c, h=h + 2 * conv.pad, w=wdt + 2 * conv.pad,
-                    o=conv.c_out, k=conv.k, stride=conv.stride,
-                    pool=unit.pool.p if unit.pool is not None else None,
-                    occupancy=occ, weight_density=wd, batch=batch)
-                timings.append(LayerTiming(
-                    index=unit.index, kind=kind, impl=impl, occupancy=occ,
-                    weight_density=wd, batch=batch, block_c=plan.block_c,
-                    measured_us=t.median_us, spread=t.spread,
-                    predicted_us=unit_model_us(
-                        kind, impl, unit, occupancy=occ, weight_density=wd,
-                        batch=batch, block_c=plan.block_c),
-                    flops=float(cost["flops"]), bytes=float(cost["bytes"])))
-            x = run_unit(x, w, unit, "conv", "dense")  # next layer's input
+        walk_graph(graph, calib, on_unit)
     dev = jax.devices()[0]
     return ProfileReport(graph_name=graph.name,
                          device_kind=getattr(dev, "device_kind", dev.platform),
                          batch=batch, block_c=plan.block_c,
-                         timings=tuple(timings), units=units)
+                         timings=tuple(timings), units=graph.units())

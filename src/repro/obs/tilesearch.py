@@ -269,8 +269,9 @@ def tile_search(plan, params, calib, *, iters: int = 2, warmup: int = 1,
     """
     import jax
 
-    from repro.graph.executor import run_unit
+    from repro.graph.executor import run_unit, walk_graph
     from repro.graph.ir import graph_weights
+    from repro.graph.registry import get_op
     from repro.obs.calibrate import CalibrationDB
     from repro.obs.constants import device_peaks
     from repro.obs.trace import NULL_TRACER
@@ -286,20 +287,22 @@ def tile_search(plan, params, calib, *, iters: int = 2, warmup: int = 1,
     db = db if db is not None else CalibrationDB()
     conv_ws, _ = graph_weights(params)
     rows: list = []
-    x = calib
-    with tracer.span("tile_search", graph=graph.name, batch=batch):
-        for lp, (unit, w) in zip(plan.layers, zip(graph.units(), conv_ws)):
-            r = search_layer(unit, w, x, lp.kind, lp.impl, iters=iters,
-                             warmup=warmup, prune_factor=prune_factor,
-                             max_timed=max_timed, calibration=calibration,
-                             tracer=tracer)
-            rows.append(r)
-            from repro.graph.registry import get_op
 
-            if get_op(lp.kind, lp.impl).pallas:
-                db.put_tile(lp.kind, lp.impl, r.shape_key,
-                            TileConfig.from_key(r.best.key))
-            x = run_unit(x, w, unit, "conv", "dense")  # dense-oracle walk
+    def on_unit(unit, x):
+        lp = plan.layers[unit.index]
+        w = conv_ws[unit.index]
+        r = search_layer(unit, w, x, lp.kind, lp.impl, iters=iters,
+                         warmup=warmup, prune_factor=prune_factor,
+                         max_timed=max_timed, calibration=calibration,
+                         tracer=tracer)
+        rows.append(r)
+        if get_op(lp.kind, lp.impl).pallas:
+            db.put_tile(lp.kind, lp.impl, r.shape_key,
+                        TileConfig.from_key(r.best.key))
+        return run_unit(x, w, unit, "conv", "dense")  # dense-oracle walk
+
+    with tracer.span("tile_search", graph=graph.name, batch=batch):
+        walk_graph(graph, calib, on_unit)
     if fit and calibration is None:
         # per-(kind, impl, tile) entries from every timed candidate, the
         # fit_report rule: scale = median(modeled_default_us / measured_us).

@@ -46,6 +46,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **kw) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -106,6 +109,10 @@ class _SpanCtx:
     def annotate(self, **kw) -> None:
         """Attach args discovered mid-span (e.g. the measured batch fill)."""
         self.args.update(kw)
+
+    # the name a profiler span (`jax.profiler.TraceAnnotation`) gives it, so
+    # a caller attaches late args the same way under every tracer
+    set_metadata = annotate
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:

@@ -2,11 +2,12 @@
 
 The paper's win is layer-dependent (Fig. 9: early layers are dense and big,
 deep layers are small and very sparse), so a whole-network setting is always
-wrong somewhere. The planner walks a `LayerGraph` (any linear CNN — VGG-19,
-LeNet, AlexNet; a `CNNConfig` is lowered via `as_graph`) on a calibration
-batch, measures per conv unit the channel-block occupancy the ECR kernel
-would actually run at — the post-compaction ceil(n_live/bc)/n_cb of
-DESIGN.md §2.2, averaged over samples — and emits a `PipelinePlan`: one
+wrong somewhere. The planner walks a `LayerGraph` (VGG-19, LeNet, AlexNet,
+GoogLeNet's branches; a `CNNConfig` is lowered via `as_graph`) on a
+calibration batch, measures per conv unit, on the tensor that unit reads,
+the channel-block occupancy the ECR kernel would actually run at — the
+post-compaction ceil(n_live/bc)/n_cb of DESIGN.md §2.2, averaged over
+samples — and emits a `PipelinePlan`: one
 `LayerPlan` per conv unit, fused with its pooling (PECR) when the unit is
 sparse AND the registry's fusion rule admits it (adjacent ReLU+pool,
 stride == p, exact tiling), left as conv + unfused pool otherwise.
@@ -29,13 +30,15 @@ sizes, keep the best plan) attach.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.graph import as_graph
-from repro.graph.executor import run_head, run_unit
-from repro.graph.ir import ConvSpec, LayerGraph, PoolSpec, graph_weights
+from repro.graph.executor import run_head, run_unit, walk_graph
+from repro.graph.ir import ConvSpec, ConvUnit, LayerGraph, PoolSpec, graph_weights
 from repro.graph.registry import fusion_eligible, get_op, unit_model_us
 from repro.kernels.tiles import TileConfig, resolve_block_c
 
@@ -57,21 +60,22 @@ class LayerPlan:
     pool: PoolSpec | None = None  # adjacent pool node (None = in-stage conv)
     weight_density: float = 1.0  # measured BSR block density of the params
     tile: TileConfig | None = None  # searched kernel geometry (None = defaults)
+    reads: int = -1  # the unit whose output this one reads (ConvUnit.reads)
 
     def to_unit(self):
         """The `ConvUnit` this plan entry executes. The LayerPlan is the
-        single source of structural truth at run time — `run_plan` executes
-        from here, never by re-walking `plan.graph` (a mismatched graph must
-        not be able to change what a validated plan runs)."""
-        from repro.graph.ir import ConvUnit
-
+        single source of each unit's structure at run time — `run_plan` runs
+        this unit where `plan.graph`'s walk reaches it, and the verifier
+        refuses a plan whose units and graph disagree (a mismatched graph
+        must not be able to change what a validated plan runs)."""
         if self.conv.c_out == 0:
             raise ValueError(
                 f"conv_{self.index + 1} carries no ConvSpec — this plan "
                 "predates the LayerGraph IR; rebuild it with plan_network")
         return ConvUnit(index=self.index, stage=self.stage, slot=self.slot,
                         conv=self.conv, relu=self.relu, pool=self.pool,
-                        in_shape=self.in_shape, out_shape=self.out_shape)
+                        in_shape=self.in_shape, out_shape=self.out_shape,
+                        reads=self.reads)
 
 
 @dataclass(frozen=True)
@@ -145,6 +149,26 @@ def measure_occupancy(x, block_c: int = 0, tile=None) -> float:
     return float(occupancy_stat(x, block_c, tile=tile))
 
 
+@partial(jax.jit, static_argnames=("graph", "block_c"))
+def _calibration_pass(conv_ws, calib, *, graph: LayerGraph, block_c: int):
+    """What the planner measures, as one compiled program (op by op, a graph
+    of many units compiles hundreds of small programs): each conv unit's
+    input on `calib`, walked with the dense oracle; its channel-block
+    occupancy (`occupancy_stat` at `block_c`); and the mask of the unit's
+    weight blocks that hold a nonzero."""
+    from repro.sparse_weights.format import weight_block_mask
+
+    xs, occs = [], []
+
+    def on_unit(unit, x):
+        xs.append(x)
+        occs.append(occupancy_stat(x, block_c))
+        return run_unit(x, conv_ws[unit.index], unit, "conv", "dense")
+
+    walk_graph(graph, calib, on_unit)
+    return xs, jnp.stack(occs), [weight_block_mask(w) for w in conv_ws]
+
+
 def plan_network(
     params,
     calib,
@@ -209,7 +233,7 @@ def plan_network(
     mirroring how pruning reports `PruneReport`).
     """
     from repro.obs.calibrate import unit_shape_key
-    from repro.sparse_weights import weight_block_density
+    from repro.sparse_weights.format import block_density
 
     graph = as_graph(graph)
     if calib.ndim == 3:
@@ -218,14 +242,20 @@ def plan_network(
         calibration = None  # empty DB == no calibration, one code path
     sparse_conv = "ecr_pallas" if use_pallas else "ecr"
     conv_ws, _ = graph_weights(params)
+    if len(conv_ws) != len(graph.units()):
+        raise ValueError(f"params carry {len(conv_ws)} conv weights but "
+                         f"{graph.name} has {len(graph.units())} conv units")
     layers = []
     fp32_alt: dict = {}  # conv index -> the (kind, impl, tile, occ) int8 displaced
     q_saving: dict = {}  # conv index -> modeled us the int8 upgrade saved
-    x = calib
     batch = int(calib.shape[0])
-    for unit, w in zip(graph.units(), conv_ws):
-        occ = measure_occupancy(x, block_c)
-        wd = weight_block_density(w)
+    xs, occs, masks = _calibration_pass(conv_ws, calib, graph=graph,
+                                        block_c=block_c)
+    occs = np.asarray(occs)
+    for unit in graph.units():
+        x = xs[unit.index]
+        occ = float(occs[unit.index])
+        wd = block_density(masks[unit.index])
         go_sparse = occ <= occ_threshold
         if go_sparse:
             fused = get_op("conv", sparse_conv).fused_with
@@ -285,8 +315,6 @@ def plan_network(
                     fp32_alt[unit.index] = (kind, impl, tile, occ)
                     q_saving[unit.index] = base_us - q_us
                     kind, impl, tile, occ = "conv", q_impl, q_tile, q_occ
-        # the dense oracle produces the next calibration input
-        x = run_unit(x, w, unit, "conv", "dense")
         layers.append(
             LayerPlan(
                 index=unit.index,
@@ -302,6 +330,7 @@ def plan_network(
                 pool=unit.pool,
                 weight_density=wd,
                 tile=tile,
+                reads=unit.reads,
             )
         )
     plan = PipelinePlan(layers=tuple(layers), occ_threshold=occ_threshold,
@@ -399,11 +428,14 @@ def run_plan(plan: PipelinePlan, params, imgs, ccfg=None, *,
              axis_name: str | None = None):
     """Execute the planned layer sequence over a batch: (N,C,H,W) -> logits.
 
-    Each entry is one whole-batch op resolved through the registry: the fused
-    Pallas grid for sparse fused units, conv + ReLU (+ unfused pool)
-    otherwise. Pallas layers run at the plan's `block_c` — the block size the
-    occupancy was measured (and the sparse/dense decision made) at. `ccfg` is
-    only consulted for pre-IR plans that carry no graph.
+    The plan's graph is walked (`walk_graph`): each entry is one whole-batch
+    op resolved through the registry, run on the tensor its unit reads — the
+    fused Pallas grid for sparse fused units, conv + ReLU (+ unfused pool)
+    otherwise — and the graph's stand-alone pools, LRNs and concats run
+    between them under their own scopes. Pallas layers run at the plan's
+    `block_c` — the block size the occupancy was measured (and the
+    sparse/dense decision made) at. `ccfg` is only consulted for pre-IR
+    plans that carry no graph.
 
     collect_occupancy=True additionally returns the per-layer observed
     channel-block occupancy of each layer's INPUT (a (n_layers,) array,
@@ -423,10 +455,10 @@ def run_plan(plan: PipelinePlan, params, imgs, ccfg=None, *,
     validate_plan(plan, params, imgs, ccfg)
     graph = _plan_graph(plan, ccfg)
     conv_ws, dense_ws = graph_weights(params)
-    x = imgs
     occs = []
-    for lp, w in zip(plan.layers, conv_ws):
-        lp_tile = getattr(lp, "tile", None)
+
+    def on_unit(unit, x):
+        lp = plan.layers[unit.index]
         # named scopes put each layer's ops under conv<i> (and the head's
         # under head) in the compiled program's op metadata, so a profiler
         # trace attributes device time per layer; they change no op
@@ -434,9 +466,11 @@ def run_plan(plan: PipelinePlan, params, imgs, ccfg=None, *,
             if collect_occupancy:
                 with jax.named_scope("occupancy"):
                     occs.append(occupancy_stat(x, plan.block_c, n_valid,
-                                               tile=lp_tile))
-            x = run_unit(x, w, lp.to_unit(), lp.kind, lp.impl, plan.block_c,
-                         tile=lp_tile)
+                                               tile=lp.tile))
+            return run_unit(x, conv_ws[lp.index], lp.to_unit(), lp.kind,
+                            lp.impl, plan.block_c, tile=lp.tile)
+
+    x = walk_graph(graph, imgs, on_unit)
     with jax.named_scope("head"):
         logits = run_head(x, dense_ws, graph.head())
     if collect_occupancy:

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.graph import as_graph
+from repro.graph import as_graph, get_op
 from repro.obs.trace import PROFILER_TRACER
 from repro.parallel.api import data_mesh, sharding_for
 from repro.pipeline.planner import PipelinePlan, plan_network, run_plan, run_plan_sharded
@@ -78,6 +78,20 @@ def auto_mesh(max_batch: int = 8, min_bucket: int = 2):
     fits = [d for d in range(1, n_avail + 1)
             if max_batch % d == 0 and max_batch // d >= min_bucket]
     return data_mesh(max(fits) if fits else 1)
+
+
+def plan_span_args(plan: PipelinePlan) -> dict:
+    """What a `serve.plan` span records of the plan it made: the graph's
+    conv units and concats, the units on an activation-sparse (ECR-family)
+    impl, and that count per branch module (`ecr_<module name>`)."""
+    sparse = {lp.index for lp in plan.layers
+              if get_op(lp.kind, lp.impl).sparse}
+    joins = plan.graph.joins() if plan.graph is not None else ()
+    args = {"units": len(plan.layers), "concats": len(joins),
+            "ecr": len(sparse)}
+    for name, idx in joins:
+        args[f"ecr_{name}"] = len(sparse.intersection(idx))
+    return args
 
 
 def _make_runner(plan: PipelinePlan, mesh=None):
@@ -154,13 +168,14 @@ class Engine:
             if calib is None:
                 raise ValueError("Engine needs either a prebuilt plan= or calib= images to plan on")
             with self.tracer.span("serve.plan", graph=graph.name,
-                                  occ_threshold=occ_threshold):
+                                  occ_threshold=occ_threshold) as sp:
                 plan = plan_network(params, calib, graph,
                                     occ_threshold=occ_threshold,
                                     block_c=block_c, use_pallas=use_pallas,
                                     calibration=calibration, tiles=tiles,
                                     int8=self.int8,
                                     int8_budget=self.int8_budget)
+                sp.set_metadata(**plan_span_args(plan))
         # mesh="auto": 1-D data mesh over the largest local-device prefix
         # dividing max_batch (all devices when they divide; fewer on awkward
         # hosts rather than refusing to construct); a 1-device mesh (every
@@ -568,7 +583,7 @@ class Engine:
                 raise ValueError("hot_swap needs plan= or calib= before the "
                                  "engine has executed its first batch")
             with self.tracer.span("serve.plan", graph=self.graph.name,
-                                  trigger="hot_swap"):
+                                  trigger="hot_swap") as sp:
                 plan = plan_network(params, jnp.asarray(calib), self.graph,
                                     occ_threshold=self.plan.occ_threshold,
                                     block_c=self.plan.block_c,
@@ -576,6 +591,7 @@ class Engine:
                                     calibration=self.calibration,
                                     tiles=self.tiles, int8=self.int8,
                                     int8_budget=self.int8_budget)
+                sp.set_metadata(**plan_span_args(plan))
         elif not self._verify_candidate(plan, params):
             return False
         with self._lock:

@@ -67,19 +67,23 @@ def block_norms(m, block: tuple):
     return jnp.sqrt((mp.reshape(nr, bt, nc, bf) ** 2).sum(axis=(1, 3)))
 
 
+def block_density(mask) -> float:
+    """Fraction of True entries of a block mask (the grid size is the
+    denominator: every block overlaps real weight, a ragged edge pads by
+    less than one block)."""
+    return float(mask.sum()) / max(mask.size, 1)
+
+
 def matrix_block_density(m, block: tuple) -> float:
-    """Fraction of (bt, bf) blocks of a 2-D matrix with any nonzero entry
-    (every block overlaps real weight — a ragged edge pads by less than one
-    block — so the grid size is the denominator)."""
-    norms = block_norms(m, block)
-    return float((norms > 0).sum()) / max(norms.size, 1)
+    """Fraction of (bt, bf) blocks of a 2-D matrix with any nonzero entry."""
+    return block_density(block_norms(m, block) > 0)
 
 
-def weight_block_density(w) -> float:
-    """Achieved block density of one conv weight (O, C, kh, kw) — or of a
-    dense-head weight (d_in, d_out), measured on its (d_out, d_in) GEMM
-    orientation — at the layer's own `weight_block` tiling. 1.0 for any
-    unpruned (fully dense) weight."""
+def weight_block_mask(w):
+    """Which blocks of one weight hold any nonzero (traceable): a conv weight
+    (O, C, kh, kw) on its (O, K) GEMM view, a dense-head weight (d_in, d_out)
+    on its (d_out, d_in) orientation, at the layer's own `weight_block`
+    tiling."""
     if w.ndim == 4:
         m = conv_weight_matrix(w)
     elif w.ndim == 2:
@@ -87,4 +91,10 @@ def weight_block_density(w) -> float:
     else:
         raise ValueError(f"weight_block_density expects a conv (O,C,kh,kw) or "
                          f"dense (d_in,d_out) weight, got shape {w.shape}")
-    return matrix_block_density(m, weight_block(m.shape[0], m.shape[1]))
+    return block_norms(m, weight_block(m.shape[0], m.shape[1])) > 0
+
+
+def weight_block_density(w) -> float:
+    """Achieved block density of one weight (`weight_block_mask`). 1.0 for
+    any unpruned (fully dense) weight."""
+    return block_density(weight_block_mask(w))

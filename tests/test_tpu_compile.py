@@ -1,9 +1,9 @@
 """Compile-only rehearsals of the serving path's Pallas kernels for a TPU v5e.
 
 Each test compiles one op at the widths the VGG-19 `--full` plan (and
-AlexNet-224) hands it, for a v5e that is described, not attached: the TPU
-compiler runs here and refuses what the chip would refuse (block shapes,
-in-kernel ops, VMEM). Every test asserts `tpu_custom_call` in the compiled
+AlexNet-224 and GoogLeNet-224) hands it, for a v5e that is described, not
+attached: the TPU compiler runs here and refuses what the chip would refuse
+(block shapes, in-kernel ops, VMEM). Every test asserts `tpu_custom_call` in the compiled
 text, which proves the Mosaic branch of `repro.kernels.platform` was taken
 and not the interpreter's HLO. Nothing runs, so nothing here says anything
 about results or times.
@@ -80,6 +80,25 @@ def test_pecr_fused_compiles_for_v5e(one_chip, shape, block_c):
 
 def test_alexnet_conv4_compiles_for_v5e(one_chip):
     text = _compiled_text(ecr_conv, *_conv_specs(one_chip, *ALEXNET_CONV4))
+    assert "tpu_custom_call" in text
+
+
+# GoogLeNet-224's inception convs the planner puts on ECR, at the cell's
+# 8-channel blocks: (C, padded H=W, O, k) — 1x1s on the 28/14/7 maps at
+# 192-832 input channels (3a, 4a, 5b), 5x5s padded by 2 at 28 and 7 (3a, 5b)
+INCEPTION = {
+    "3a-1x1": (192, 28, 64, 1), "4a-1x1": (480, 14, 192, 1),
+    "5b-1x1": (832, 7, 384, 1), "3a-5x5": (16, 32, 32, 5),
+    "5b-5x5": (48, 11, 128, 5),
+}
+
+
+@pytest.mark.parametrize("name", sorted(INCEPTION))
+def test_inception_ecr_compiles_for_v5e(one_chip, name):
+    c, hp, o, k = INCEPTION[name]
+    text = _compiled_text(lambda x, w: ecr_conv(x, w, block_c=8),
+                          _spec(one_chip, (BATCH, c, hp, hp)),
+                          _spec(one_chip, (o, c, k, k)))
     assert "tpu_custom_call" in text
 
 
