@@ -22,6 +22,8 @@ and the only collective (the cross-shard occupancy aggregation) lives in
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -48,6 +50,13 @@ def maxpool2d(x, pool: PoolSpec):
     "floor" drops the tail; "ceil" pads with -inf to keep a partial window.
     A max-pool's `pad` is -inf on each edge; kind="avg" averages each whole
     window.
+
+    A max-pool is separable: the max of `p` strided shifted slices along H,
+    then along W, of the padded map. XLA fuses those into loop fusions; a
+    `reduce-window` over NCHW maps with 14 or 7 on the lanes took about
+    1.8 ms a pool at a batch of 8 on a TPU v5e, GoogLeNet's inception pools
+    nearly half its device time. The max is exact, so the result is the
+    `reduce-window`'s bit for bit.
     """
     from repro.graph.ir import pool_out_len
 
@@ -62,14 +71,17 @@ def maxpool2d(x, pool: PoolSpec):
         return total / (pool.p * pool.p)
     tail_h = (oh - 1) * pool.s + pool.p - h - 2 * pool.pad
     tail_w = (ow - 1) * pool.s + pool.p - w - 2 * pool.pad
-    return jax.lax.reduce_window(
-        x,
-        -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min,
-        jax.lax.max,
-        padding=((0, 0),) * lead + ((pool.pad, pool.pad + max(tail_h, 0)),
-                                    (pool.pad, pool.pad + max(tail_w, 0))),
-        **window,
-    )
+    x = jnp.pad(
+        x, ((0, 0),) * lead + ((pool.pad, pool.pad + max(tail_h, 0)),
+                               (pool.pad, pool.pad + max(tail_w, 0))),
+        constant_values=-jnp.inf if jnp.issubdtype(x.dtype, jnp.floating)
+        else jnp.iinfo(x.dtype).min)
+    for axis, n in ((x.ndim - 2, oh), (x.ndim - 1, ow)):
+        span = (n - 1) * pool.s + 1
+        x = functools.reduce(jnp.maximum, (
+            jax.lax.slice_in_dim(x, i, i + span, stride=pool.s, axis=axis)
+            for i in range(pool.p)))
+    return x
 
 
 def lrn(x, spec: LRN):

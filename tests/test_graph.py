@@ -134,6 +134,68 @@ def test_models_maxpool_compat_modes():
     assert _maxpool(jnp.zeros((1, 5, 5)), 2, mode="floor").shape == (1, 2, 2)
 
 
+def _reduce_window_maxpool(x, pool: PoolSpec):
+    """The plain oracle: one `reduce_window(max)`, -inf (or the int minimum)
+    on each edge and in the ceil tail."""
+    from repro.graph.ir import pool_out_len
+
+    h, w = x.shape[-2:]
+    oh, ow = pool_out_len(h, pool), pool_out_len(w, pool)
+    tail_h = max((oh - 1) * pool.s + pool.p - h - 2 * pool.pad, 0)
+    tail_w = max((ow - 1) * pool.s + pool.p - w - 2 * pool.pad, 0)
+    lead = x.ndim - 2
+    fill = (-jnp.inf if jnp.issubdtype(x.dtype, jnp.floating)
+            else jnp.iinfo(x.dtype).min)
+    return jax.lax.reduce_window(
+        x, fill, jax.lax.max,
+        window_dimensions=(1,) * lead + (pool.p, pool.p),
+        window_strides=(1,) * lead + (pool.s, pool.s),
+        padding=((0, 0),) * lead + ((pool.pad, pool.pad + tail_h),
+                                    (pool.pad, pool.pad + tail_w)))
+
+
+@pytest.mark.parametrize("shape,pool,dtype,negative", [
+    ((2, 6, 8, 8), PoolSpec(2), jnp.float32, False),
+    ((3, 9, 9), PoolSpec(2, mode="floor"), jnp.float32, False),
+    ((2, 4, 13, 13), PoolSpec(3, stride=2), jnp.float32, False),
+    ((2, 3, 10, 10), PoolSpec(3, stride=2, mode="floor"), jnp.float32, False),
+    ((2, 3, 10, 9), PoolSpec(3, stride=2, mode="ceil"), jnp.float32, True),
+    ((1, 512, 14, 14), PoolSpec(3, stride=1, pad=1), jnp.float32, False),
+    ((1, 8, 14, 14), PoolSpec(3, stride=1, pad=1), jnp.float32, True),
+    ((2, 8, 7, 7), PoolSpec(3, stride=1, pad=1), jnp.float32, True),
+    ((1, 16, 14, 14), PoolSpec(3, stride=2, mode="ceil"), jnp.float32, True),
+    ((1, 16, 28, 28), PoolSpec(3, stride=2, mode="ceil"), jnp.float32, False),
+    ((1, 4, 112, 112), PoolSpec(3, stride=2, mode="ceil"), jnp.float32, True),
+    ((1, 4, 11, 11), PoolSpec(3, stride=2, pad=1, mode="ceil"), jnp.float32, True),
+    ((4, 10, 10), PoolSpec(2, stride=2, pad=1), jnp.float32, True),
+    ((1, 8, 55, 55), PoolSpec(3, stride=2), jnp.float32, False),
+    ((1, 8, 96, 96), PoolSpec(2), jnp.float32, False),
+    ((2, 5, 14, 14), PoolSpec(3, stride=1, pad=1), jnp.int32, True),
+    ((5, 13, 12), PoolSpec(3, stride=2, mode="ceil"), jnp.int32, False),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else None)
+def test_maxpool_equals_reduce_window(shape, pool, dtype, negative):
+    """The shifted-slice max-pool is the `reduce_window` one, bit for bit:
+    every mode, pad, rank and dtype; all-negative maps show the fill."""
+    x = jax.random.normal(jax.random.PRNGKey(sum(shape)), shape) * 100
+    if negative:
+        x = -jnp.abs(x) - 1
+    x = x.astype(dtype)
+    out = maxpool2d(x, pool)
+    want = _reduce_window_maxpool(x, pool)
+    assert out.shape == want.shape and out.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def test_maxpool_lowers_without_reduce_window():
+    """GoogLeNet's 4b pool (3x3/1 pad 1 on 512 x 14 x 14) lowers to slices
+    and maxima: a `reduce-window` there took 1.8 ms a batch on a v5e."""
+    x = jax.ShapeDtypeStruct((1, 512, 14, 14), jnp.float32)
+    pool = PoolSpec(3, stride=1, pad=1)
+    hlo = jax.jit(lambda x: maxpool2d(x, pool)).lower(x).compile().as_text()
+    assert "reduce-window" not in hlo
+    assert "maximum" in hlo
+
+
 # ---------------------------------------------------------------------------
 # registry: one dispatch site, fusion rule
 # ---------------------------------------------------------------------------
